@@ -27,13 +27,12 @@ Full-summary keys: ``{"metric", "value", "unit", "vs_baseline",
 - ``northstar``: the BASELINE.md target metric — samples/sec/chip +
   infeed-stall % of real train steps (MLP on png images, transformer LM on
   token windows) fed through make_reader -> JaxDataLoader ->
-  prefetch_to_device, on the TPU when one is usable (CPU fallback flagged
-  via ``platform``).
+  prefetch_to_device, on the TPU (a CPU rehearsal needs an explicit
+  ``JAX_PLATFORMS=cpu`` and is labelled by ``platform``).
 """
 
 import json
 import os
-import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -48,25 +47,17 @@ TOKENS_PATH = '/tmp/petastorm_tpu_northstar_tokens'
 IMAGENET_PATH = '/tmp/petastorm_tpu_northstar_imagenet_photo'
 
 
-def _probe_platform():
-    """The ambient jax backend's platform name ('tpu', 'gpu', ...) if it
-    initializes cleanly, else 'cpu' (forced via env BEFORE this process
-    imports jax). Probing in a throwaway subprocess keeps a broken TPU
-    runtime (e.g. libtpu version mismatch) from poisoning the bench
-    process."""
-    try:
-        out = subprocess.run(
-            [sys.executable, '-c',
-             'import jax; d = jax.devices(); print(d[0].platform)'],
-            env=dict(os.environ), capture_output=True, timeout=180)
-        if out.returncode == 0:
-            platform = out.stdout.decode().strip().splitlines()[-1]
-            if platform:
-                return platform
-    except Exception:
-        pass
-    os.environ['JAX_PLATFORMS'] = 'cpu'
-    return 'cpu'
+def _platform():
+    """The platform JAX runs on, read in this process. Off the TPU the
+    bench runs only under an explicit ``JAX_PLATFORMS=cpu``, as a rehearsal
+    labelled ``cpu``; any other failure to find the chip is an error."""
+    import jax
+    platform = jax.devices()[0].platform
+    if platform != 'tpu' and os.environ.get('JAX_PLATFORMS') != 'cpu':
+        raise SystemExit('bench.py: no TPU found (JAX reports {!r}); set '
+                         'JAX_PLATFORMS=cpu for a CPU rehearsal'.format(
+                             platform))
+    return platform
 
 
 def _ensure(path, marker, generate):
@@ -206,7 +197,9 @@ def main(argv=None):
                              '(stdout carries only the bounded compact '
                              'summary line)')
     args = parser.parse_args(argv)
-    platform = _probe_platform()
+    platform = _platform()
+    from petastorm_tpu.utils import configure_compile_cache
+    configure_compile_cache(os.path.dirname(os.path.abspath(__file__)))
 
     from petastorm_tpu.benchmark import northstar
     from petastorm_tpu.benchmark.hello_world import generate_hello_world_dataset
@@ -326,9 +319,9 @@ def main(argv=None):
     chaos_bench = run_chaos_bench(quick=True)
 
     # -- north-star: train-step infeed overlap ------------------------------
-    # Accelerator-scale configs for any non-CPU backend; dataset paths carry
-    # the size parameters so a platform change can't reuse a stale store.
-    on_tpu = platform != 'cpu'
+    # Chip-scale configs on the TPU; dataset paths carry the size parameters
+    # so a platform change can't reuse a stale store.
+    on_tpu = platform == 'tpu'
     mnist_rows = 16384 if on_tpu else 2048
     mnist_batch = 512 if on_tpu else 128
     seq_len = 256 if on_tpu else 128

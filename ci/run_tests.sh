@@ -139,7 +139,7 @@ python ci/check_perf_regression.py
 
 echo '== multi-chip dry run (8 virtual devices) =='
 XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-    python __graft_entry__.py 8 --dryrun-only
+    python __graft_entry__.py 8
 
 # The type gate is a DECLARED guarantee: inside OUR docker image (which
 # pins mypy via dev-requirements.txt and sets PETASTORM_TPU_IMAGE=1) a

@@ -40,6 +40,7 @@ import json
 import multiprocessing
 import os
 import shutil
+import sys
 import tempfile
 import time
 
@@ -109,7 +110,12 @@ def _consume_all(url: str, **reader_kwargs) -> dict:
 
 
 def _reader_proc(url, seed, kwargs, out_queue):
-    """One fleet member (module-level: spawn-picklable)."""
+    """One fleet member (module-level: spawn-picklable). Pinned to the CPU
+    before anything imports jax: its reader plans decode with
+    ``jax.devices()``, and the chip belongs to the parent process."""
+    os.environ['JAX_PLATFORMS'] = 'cpu'
+    if 'jax' in sys.modules:     # imported with the parent's main module
+        sys.modules['jax'].config.update('jax_platforms', 'cpu')
     try:
         out_queue.put(_consume_all(url, seed=seed, **kwargs))
     except BaseException as e:  # noqa: BLE001 - shipped to the parent

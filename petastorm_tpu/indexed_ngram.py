@@ -190,7 +190,7 @@ class IndexedNGramLoader(IndexedBatchLoader):
         groups by construction (a window never crosses a piece), so gathering
         the ``(n_offsets, B)`` row matrix in one call amortizes the per-gather
         searchsorted/unique/cache-lock overhead that serialized the round-4
-        per-offset loop (the 83.75%-overlap stall in BENCH_r04)."""
+        per-offset loop."""
         win_ids = self._batch_rows(epoch, batch)     # global window indices
         piece_ids = self._win_piece[win_ids]
         # global ts-sorted position of each window's base row

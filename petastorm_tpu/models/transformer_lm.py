@@ -4,7 +4,9 @@ Parallelism is expressed entirely through GSPMD shardings over a named mesh
 (axes from ``petastorm_tpu.parallel.mesh``): annotate params/activations with
 PartitionSpecs, let XLA insert the collectives.
 
-- **dp** ('data'): batch dim of activations.
+- **dp** ('data'): batch dim of activations. Flash attention runs under
+  shard_map over this axis (and 'model' on heads): Pallas kernels cannot be
+  partitioned by GSPMD.
 - **tp** ('model'): Megatron-style column/row parallel attention + MLP —
   wq/wk/wv and w_gate/w_up are column-parallel (output dim sharded), wo and
   w_down row-parallel (input dim sharded); XLA inserts the psum where the
@@ -30,7 +32,7 @@ import jax
 import jax.numpy as jnp
 
 from petastorm_tpu.ops.attention import blockwise_attention, flash_attention
-from petastorm_tpu.parallel.ring import ring_attention
+from petastorm_tpu.parallel.ring import resolve_ring_impl, ring_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -212,17 +214,42 @@ def _ring_attention_sharded(q, k, v, mesh):
              'seq', None)
     # Resolve from the mesh devices (not the session default backend): TPU
     # meshes get per-chunk Pallas kernels, CPU meshes the jnp path.
-    from petastorm_tpu.parallel.ring import resolve_ring_impl
     impl = resolve_ring_impl(None, mesh)
 
-    from petastorm_tpu.parallel.mesh import shard_map_fn
-
-    @functools.partial(shard_map_fn(), mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec), out_specs=spec)
     def fn(q, k, v):
         return ring_attention(q, k, v, 'seq', causal=True, impl=impl)
 
     return fn(q, k, v)
+
+
+def _flash_attention_sharded(q, k, v, mesh, segment_ids=None, window=None):
+    """Flash attention under shard_map: Mosaic kernels cannot be partitioned
+    by GSPMD, so each device runs the kernel on its own batch shard (and its
+    own heads when the mesh has 'model'). q/k/v are global (B, H, L, dh)
+    arrays; a sharded 'seq' axis is gathered, as the unsharded call would.
+    The kernel backend follows the mesh's devices, as ring attention's does."""
+    from jax.sharding import PartitionSpec as P
+
+    names = set(mesh.axis_names)
+    batch = 'data' if 'data' in names else None
+    heads = 'model' if 'model' in names else None
+    spec = P(batch, heads, None, None)
+    backend = resolve_ring_impl(None, mesh)       # 'pallas' on TPU meshes
+    in_specs = (spec, spec, spec)
+    args = (q, k, v)
+    if segment_ids is not None:
+        in_specs += (P(batch, None),)
+        args += (segment_ids,)
+
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                       out_specs=spec)
+    def fn(q, k, v, segment_ids=None):
+        return flash_attention(q, k, v, causal=True, segment_ids=segment_ids,
+                               window=window, backend=backend)
+
+    return fn(*args)
 
 
 def _attention(x, layer, config: TransformerConfig, positions, mesh=None,
@@ -258,6 +285,9 @@ def _attention(x, layer, config: TransformerConfig, positions, mesh=None,
         if mesh is None or 'seq' not in mesh.axis_names:
             raise ValueError("attention='ring' needs a mesh with a 'seq' axis")
         o = _ring_attention_sharded(q, k, v, mesh)
+    elif c.attention == 'flash' and mesh is not None:
+        o = _flash_attention_sharded(q, k, v, mesh, segment_ids,
+                                     c.attention_window)
     elif c.attention == 'flash':
         o = flash_attention(q, k, v, causal=True, segment_ids=segment_ids,
                             window=c.attention_window)
@@ -623,7 +653,8 @@ def make_train_step(config: TransformerConfig, mesh=None, optimizer=None):
 
     With ``mesh``, params/activations are constrained to :func:`param_specs` /
     :func:`batch_spec` shardings (dp/tp/sp/ep as present in the mesh); ring
-    attention additionally runs under shard_map on the 'seq' axis.
+    and flash attention additionally run under shard_map, and the returned
+    optimizer's ``init`` builds its state on the mesh.
     """
     import optax
     if optimizer is None:
@@ -640,6 +671,16 @@ def make_train_step(config: TransformerConfig, mesh=None, optimizer=None):
         return optimizer, jax.jit(step)
 
     from jax.sharding import NamedSharding
+
+    # opt_state made off the mesh (its step count) differs in type from the
+    # one the step returns, so the second step would compile again
+    base_init = optimizer.init
+
+    def init_on_mesh(params):
+        with jax.set_mesh(mesh):
+            return base_init(params)
+
+    optimizer = optimizer._replace(init=init_on_mesh)
 
     pspecs = param_specs(config, mesh)
     bspec = batch_spec(mesh)

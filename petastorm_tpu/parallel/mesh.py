@@ -17,30 +17,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import numpy as np
 
 
-def shard_map_fn():
-    """The ``shard_map`` transform across jax versions: promoted to
-    ``jax.shard_map`` in newer releases, ``jax.experimental.shard_map`` in
-    the 0.4.x line this image pins. One resolution point for every call
-    site (ring/pipeline wrappers, the LM's sharded attention).
-
-    On the 0.4.x path the returned callable defaults ``check_rep=False``:
-    the kernels in this repo declare their replication through the newer
-    varying-mesh-axes (vma) typing, which 0.4.x lacks — its legacy
-    replication checker has no rule for ``pallas_call`` at all and would
-    reject every Pallas-bearing body outright."""
-    import jax
-    fn = getattr(jax, 'shard_map', None)
-    if fn is not None:
-        return fn
-    from jax.experimental.shard_map import shard_map
-
-    def legacy_shard_map(f, **kwargs):
-        kwargs.setdefault('check_rep', False)
-        return shard_map(f, **kwargs)
-
-    return legacy_shard_map
-
-
 def make_mesh(axis_sizes: Dict[str, int], devices: Optional[Sequence] = None):
     """Build a ``jax.sharding.Mesh`` with named axes.
 

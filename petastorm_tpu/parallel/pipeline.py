@@ -85,21 +85,13 @@ def make_pipeline_fn(stage_fn, mesh, pipe_axis: str = 'pipe',
     def fn(stacked_params, microbatches):
         pspecs = jax.tree_util.tree_map(lambda _: P(pipe_axis), stacked_params)
 
-        from petastorm_tpu.parallel.mesh import shard_map_fn
-
         @functools.partial(
-            shard_map_fn(), mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(pspecs, mb_spec), out_specs=mb_spec)
         def run(stacked, mb):
             # squeeze this stage's slot of the stacked params
             my_params = jax.tree_util.tree_map(lambda a: a[0], stacked)
-            if hasattr(jax.lax, 'pcast'):
-                mb = jax.lax.pcast(mb, (pipe_axis,), to='varying')
-            elif hasattr(jax.lax, 'pvary'):
-                # pre-pcast jax: pvary is the older spelling
-                mb = jax.lax.pvary(mb, (pipe_axis,))
-            # else: pre-vma jax (0.4.x) — shard_map has no varying-axes
-            # typing, so there is nothing to cast
+            mb = jax.lax.pcast(mb, (pipe_axis,), to='varying')
             return pipeline_apply(stage_fn, my_params, mb, pipe_axis)
 
         return run(stacked_params, microbatches)

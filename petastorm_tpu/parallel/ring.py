@@ -279,9 +279,7 @@ def make_ring_attention(mesh, seq_axis: str = 'seq', causal: bool = True,
     spec = P(batch_axis, None, seq_axis, None)
     impl = resolve_ring_impl(impl, mesh)
 
-    from petastorm_tpu.parallel.mesh import shard_map_fn
-
-    @functools.partial(shard_map_fn(), mesh=mesh,
+    @functools.partial(jax.shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec), out_specs=spec)
     def fn(q, k, v):
         return ring_attention(q, k, v, seq_axis, causal=causal, impl=impl)

@@ -127,7 +127,7 @@ class RowGroupWorker(ParquetPieceWorker):
             # shared vectorized scan, and publish ONE chunk instead of
             # per-window dicts — the round-4 per-row assembler stole enough
             # worker GIL time to run 3.4x slower than its indexed twin on the
-            # identical workload (BENCH_r04). Predicate/transform items keep
+            # identical workload. Predicate/transform items keep
             # the row path: both contracts are per-row here.
             try:
                 chunk = self._form_window_chunk(piece,

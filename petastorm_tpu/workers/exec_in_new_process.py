@@ -20,10 +20,7 @@ def exec_in_new_process(func, args=(), kwargs=None) -> subprocess.Popen:
     """Serialize ``(func, args, kwargs)`` with dill to a temp file and launch
     ``python -S -m petastorm_tpu.workers.exec_in_new_process <file>``.
 
-    ``-S`` skips ``site``/``sitecustomize`` in the worker: environments that
-    register accelerator plugins at interpreter startup (e.g. a sitecustomize
-    importing jax) would otherwise pay seconds of import time per worker —
-    and workers must never touch the accelerator runtime anyway. The parent's
+    ``-S`` skips ``site`` initialization in the worker; the parent's
     fully-resolved ``sys.path`` is passed via PYTHONPATH, so everything
     importable in the parent (including ``.pth``-added entries) stays
     importable in the worker. Set ``PETASTORM_TPU_WORKER_SITE=1`` to restore

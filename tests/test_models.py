@@ -466,11 +466,7 @@ class TestMoeDispatch:
             cfg = _tiny_config(n_experts=n_experts, moe_capacity_factor=1.0)
             layer, x = self._layer_and_x(cfg)
             fn = jax.jit(lambda x: tlm._moe_ffn(x, layer, cfg)[0])
-            cost = fn.lower(x).compile().cost_analysis()
-            if isinstance(cost, (list, tuple)):
-                # 0.4.x jax returns one dict per device; newer jax a dict
-                cost = cost[0]
-            return cost['flops']
+            return fn.lower(x).compile().cost_analysis()['flops']
 
         f2, f8 = moe_flops(2), moe_flops(8)
         assert f8 < f2 * 1.5, (f2, f8)   # dense dispatch would give ~4x

@@ -509,7 +509,10 @@ class TestPerfRegressionGate:
         entries, problems = gate.load_trajectory(REPO_ROOT)
         problems.extend(gate.check_regressions(entries))
         assert not problems, problems
-        assert len(entries) >= 40
+        # PR 21 deleted the records taken through a remote chip setup that
+        # no longer exists (BENCH_r02-r04, the r05 dev run, MULTICHIP_r01);
+        # 31 of the 70 trajectory entries remain
+        assert len(entries) >= 31
 
     def test_check_bench_docs_rejects_null_parsed(self, tmp_path):
         docs_gate = _load_script('check_bench_docs',
