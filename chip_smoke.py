@@ -9,17 +9,21 @@ fresh temporary directory and removed at exit.
   stored with ``NdarrayCodec``, so the bytes-through device decode
   (``ops/decode.py``) runs; ``make_columnar_reader`` -> ``JaxDataLoader`` ->
   ``prefetch_to_device`` -> the flagship LM's train step at its default
-  widths with ``attention='flash'``. Step 0's loss is checked against the
-  jnp ``'blockwise'`` attention on the same batch and params.
+  widths with ``attention='flash'``. Step 0's loss and the first gradient
+  are checked against the jnp ``'blockwise'`` attention on the same batch
+  and params, and a control with a wrong attention mask must miss them.
 - ``image`` (default): 512 ImageNet-shaped JPEG rows; ``make_reader`` ->
   ``JaxDataLoader(batch_size=64)`` -> the ``image_cnn`` train step at
   224x224, 1000 classes.
 - ``--chips 4`` runs only the data-parallel phase: the ``lm`` store through
   ``ShardedJaxLoader`` over a ``{'data': 4}`` mesh into the same flash step,
-  compared with the same global batch and params stepped on one chip.
+  compared with the same global batch and params stepped on one chip; a
+  control on half the batch must miss the one-chip numbers.
 
 The last stdout line is ``{"ok": true, "device": {...}}``; any failure
 exits non-zero before it. Step times printed here are context, not results.
+``--seed`` fixes the stores and the weights, not the order in which the
+thread-pool readers deliver rows, so two runs may step different batches.
 """
 
 import argparse
@@ -33,10 +37,16 @@ import time
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
 
-#: Relative tolerance between two losses of the bf16 LM: flash vs blockwise
-#: attention on one chip, and four chips vs one. Both compute in bf16 with
-#: float32 softmax statistics; 1e-2 is about 2.5 bf16 ulps (2**-8).
-LOSS_RTOL = 1e-2
+#: Limits on two sound computations of the bf16 LM on one batch: flash vs
+#: blockwise attention on one chip, and four chips vs one. LOSS_RTOL bounds
+#: the relative loss gap, GRAD_RTOL the gradient's relative L2 gap over all
+#: parameters. On the chip sound runs read loss gaps up to 1.6e-5 and
+#: gradient gaps up to 1.1e-2; an attention window of seq/2 reads 2.1e-5 and
+#: 0.71 (at random init the loss barely sees attention, the gradient does),
+#: half the batch 3.9e-4 and 1.0 (PERF.md, PR 21). The controls below prove
+#: each run's limits tight enough to catch such faults.
+LOSS_RTOL = 1e-4
+GRAD_RTOL = 5e-2
 
 
 class SmokeFailure(RuntimeError):
@@ -56,9 +66,44 @@ def _workers():
     return min(8, max(2, os.cpu_count() or 2))
 
 
-def _close_enough(a, b):
+def _loss_close(a, b):
     return math.isfinite(a) and math.isfinite(b) and (
         abs(a - b) <= LOSS_RTOL * max(abs(a), abs(b)))
+
+
+def _grad_gap(got, want):
+    """``||got - want|| / ||want||`` over every leaf, on the host."""
+    import jax
+    import numpy as np
+
+    num = den = 0.0
+    for g, w in zip(jax.tree_util.tree_leaves(jax.device_get(got)),
+                    jax.tree_util.tree_leaves(jax.device_get(want))):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        num += float(np.sum(np.square(g - w), dtype=np.float64))
+        den += float(np.sum(np.square(w), dtype=np.float64))
+    return math.sqrt(num / den) if den else math.inf
+
+
+def _close_enough(what, got, want):
+    """``got``/``want``: ``(loss, grads)`` on one batch. Logs both gaps; true
+    when the loss is within LOSS_RTOL and the gradient within GRAD_RTOL."""
+    loss, want_loss = float(got[0]), float(want[0])
+    gap = _grad_gap(got[1], want[1])
+    close = _loss_close(loss, want_loss) and gap <= GRAD_RTOL
+    log('{}: loss {!r} vs {!r} (relative gap {!r}, limit {}); gradient gap '
+        '{!r} (limit {}); {}'.format(
+            what, loss, want_loss, abs(loss - want_loss) / abs(want_loss),
+            LOSS_RTOL, gap, GRAD_RTOL, 'close' if close else 'apart'))
+    return close
+
+
+def _value_and_grad(config, mesh=None):
+    import jax
+
+    from petastorm_tpu.models import transformer_lm as tlm
+    return jax.jit(jax.value_and_grad(
+        lambda p, x, y: tlm.loss_fn(p, x, y, config, mesh)))
 
 
 def _token_store(workdir, seed, rows, seq_len, vocab):
@@ -81,10 +126,11 @@ def _token_reader(url, seed):
 
 
 def lm_phase(workdir, config, *, seed=0, rows=4096, batch_size=8,
-             steps=10, vocab=None):
+             steps=10):
     """Token store -> columnar reader -> JaxDataLoader -> prefetch_to_device
-    -> ``config``'s train step. Fails on a non-finite loss, a step-0 loss
-    off the blockwise reference, or no row decoded on the device."""
+    -> ``config``'s train step. Fails on a non-finite loss, a step-0 loss or
+    first gradient off the blockwise reference, a wrong-mask control that
+    matches it, or no row decoded on the device."""
     import dataclasses
 
     import jax
@@ -94,7 +140,7 @@ def lm_phase(workdir, config, *, seed=0, rows=4096, batch_size=8,
     from petastorm_tpu.models import transformer_lm as tlm
 
     seq_len = config.max_seq_len
-    url = _token_store(workdir, seed, rows, seq_len, vocab or config.vocab_size)
+    url = _token_store(workdir, seed, rows, seq_len, config.vocab_size)
     params = tlm.init(jax.random.PRNGKey(seed), config)
     optimizer, step = tlm.make_train_step(config)
     opt_state = optimizer.init(params)
@@ -106,7 +152,9 @@ def lm_phase(workdir, config, *, seed=0, rows=4096, batch_size=8,
     log('lm: step compiled in {:.2f} s; tpu_custom_call in step: {}'.format(
         compile_s, kernel))
     ref_config = dataclasses.replace(config, attention='blockwise')
-    reference = jax.jit(lambda p, x, y: tlm.loss_fn(p, x, y, ref_config))
+    # a mask fault: each token sees only the last seq_len // 2 positions
+    control_config = dataclasses.replace(ref_config,
+                                         attention_window=seq_len // 2)
 
     losses, step_s = [], []
     with _token_reader(url, seed) as reader:
@@ -116,7 +164,9 @@ def lm_phase(workdir, config, *, seed=0, rows=4096, batch_size=8,
                 tokens = next(batches)['tokens']
                 x, y = tokens[:, :-1], tokens[:, 1:]
                 if i == 0:
-                    ref_loss = float(reference(params, x, y))
+                    flash = _value_and_grad(config)(params, x, y)
+                    ref = _value_and_grad(ref_config)(params, x, y)
+                    control = _value_and_grad(control_config)(params, x, y)
                 start = time.perf_counter()
                 params, opt_state, loss = compiled(params, opt_state, x, y)
                 loss.block_until_ready()
@@ -125,15 +175,24 @@ def lm_phase(workdir, config, *, seed=0, rows=4096, batch_size=8,
                 log('lm: step {} loss {!r} ({:.4f} s)'.format(
                     i, losses[-1], step_s[-1]))
         rows_device = reader.stats.snapshot().get('rows_decoded_device', 0)
-    log('lm: step-0 loss {!r} vs blockwise reference {!r} (|diff| {!r}, '
-        'rtol {})'.format(losses[0], ref_loss, abs(losses[0] - ref_loss),
-                          LOSS_RTOL))
+    ref_loss = float(ref[0])
+    log('lm: step-0 loss {!r} vs blockwise reference {!r} (relative gap {!r}, '
+        'limit {})'.format(losses[0], ref_loss,
+                           abs(losses[0] - ref_loss) / abs(ref_loss),
+                           LOSS_RTOL))
+    flash_close = _close_enough('lm: flash vs blockwise', flash, ref)
+    control_close = _close_enough(
+        'lm: control (window {}) vs blockwise'.format(
+            control_config.attention_window), control, ref)
     log('lm: rows_decoded_device {}'.format(rows_device))
     _require(all(math.isfinite(v) for v in losses),
              'non-finite LM loss: {}'.format(losses))
-    _require(_close_enough(losses[0], ref_loss),
+    _require(_loss_close(losses[0], ref_loss),
              'step-0 loss {!r} off the blockwise reference {!r}'.format(
                  losses[0], ref_loss))
+    _require(flash_close, 'flash loss or gradient off the blockwise reference')
+    _require(not control_close, 'the wrong-mask control matched the blockwise '
+                                'reference: the limits are too loose')
     _require(rows_device > 0, 'no row was decoded on the device')
     return {'compile_s': compile_s, 'kernel': kernel, 'losses': losses,
             'reference_loss': ref_loss, 'step_s': step_s,
@@ -200,11 +259,12 @@ def image_phase(workdir, *, seed=0, rows=512, batch_size=64, steps=3,
 
 
 def data_parallel_phase(workdir, config, devices, *, seed=0, rows=4096,
-                        global_batch=8, steps=3, vocab=None):
+                        global_batch=8, steps=3):
     """Token store -> ShardedJaxLoader over ``{'data': len(devices)}`` ->
     ``config``'s train step, params replicated; each step is repeated on
     ``devices[0]`` alone with the same global batch and params. Fails
-    unless the losses match and the batch sits on every device."""
+    unless the losses and the first gradient match, a control on half the
+    batch misses them, and the batch sits on every device."""
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -215,7 +275,7 @@ def data_parallel_phase(workdir, config, devices, *, seed=0, rows=4096,
 
     n = len(devices)
     url = _token_store(workdir, seed, rows, config.max_seq_len,
-                       vocab or config.vocab_size)
+                       config.vocab_size)
     mesh = make_mesh({'data': n}, devices=devices)
     params = tlm.init(jax.random.PRNGKey(seed), config)
     dp_optimizer, dp_step = tlm.make_train_step(config, mesh)
@@ -232,6 +292,7 @@ def data_parallel_phase(workdir, config, devices, *, seed=0, rows=4096,
         with contextlib.closing(prefetch_to_device(iter(loader))) as batches:
             for i in range(steps):
                 tokens = next(batches)['tokens']
+                host = jax.device_put(np.asarray(tokens), devices[0])
                 if i == 0:
                     shard_devices = {s.device for s in tokens.addressable_shards}
                     log('dp: batch {} on {} distinct devices'.format(
@@ -239,12 +300,22 @@ def data_parallel_phase(workdir, config, devices, *, seed=0, rows=4096,
                     _require(len(shard_devices) == n,
                              'batch shards sit on {} devices, not {}'.format(
                                  len(shard_devices), n))
+                    one_vg = _value_and_grad(config)
+                    want = one_vg(one_params, host[:, :-1], host[:, 1:])
+                    first = _close_enough(
+                        'dp: {} chips vs one'.format(n),
+                        _value_and_grad(config, mesh)(
+                            dp_params, tokens[:, :-1], tokens[:, 1:]), want)
+                    # the fault it stands for: a step on half the batch
+                    half = host[:global_batch // 2]
+                    control = _close_enough(
+                        'dp: control (half the batch) vs one chip',
+                        one_vg(one_params, half[:, :-1], half[:, 1:]), want)
                 start = time.perf_counter()
                 dp_params, dp_opt, loss = dp_step(dp_params, dp_opt,
                                                   tokens[:, :-1], tokens[:, 1:])
                 dp_losses.append(float(loss))
                 dp_s = time.perf_counter() - start
-                host = jax.device_put(np.asarray(tokens), devices[0])
                 one_params, one_opt, loss = one_step(one_params, one_opt,
                                                      host[:, :-1], host[:, 1:])
                 one_losses.append(float(loss))
@@ -257,9 +328,12 @@ def data_parallel_phase(workdir, config, devices, *, seed=0, rows=4096,
                     for d in devices]
     log('dp: rows_decoded_device {}; bytes_in_use per device {}'.format(
         rows_device, bytes_in_use))
-    _require(all(_close_enough(a, b) for a, b in zip(dp_losses, one_losses)),
+    _require(all(_loss_close(a, b) for a, b in zip(dp_losses, one_losses)),
              'data-parallel losses {} off the one-chip losses {}'.format(
                  dp_losses, one_losses))
+    _require(first, 'data-parallel loss or gradient off the one-chip step')
+    _require(not control, 'the half-batch control matched the full batch: '
+                          'the limits are too loose')
     return {'losses': dp_losses, 'one_chip_losses': one_losses,
             'bytes_in_use': bytes_in_use, 'rows_decoded_device': rows_device}
 

@@ -67,10 +67,28 @@ def test_data_parallel_phase(smoke, tmp_path):
     assert out['rows_decoded_device'] > 0
 
 
-def test_phase_fails_off_the_reference(smoke, tmp_path, monkeypatch):
-    monkeypatch.setattr(smoke, 'LOSS_RTOL', -1.0)
-    with pytest.raises(smoke.SmokeFailure, match='blockwise reference'):
+@pytest.mark.parametrize('limit,message', [
+    ('LOSS_RTOL', 'step-0 loss .* off the blockwise reference'),
+    ('GRAD_RTOL', 'flash loss or gradient off the blockwise reference')])
+def test_phase_fails_off_the_reference(smoke, tmp_path, monkeypatch, limit,
+                                       message):
+    monkeypatch.setattr(smoke, limit, -1.0)
+    with pytest.raises(smoke.SmokeFailure, match=message):
         smoke.lm_phase(str(tmp_path), TINY, rows=16, batch_size=4, steps=1)
+
+
+@pytest.mark.parametrize('phase', ['lm', 'data_parallel'])
+def test_loose_limits_fail_the_control(smoke, tmp_path, monkeypatch, phase):
+    """Limits a wrong mask or half the batch would pass are refused."""
+    monkeypatch.setattr(smoke, 'LOSS_RTOL', float('inf'))
+    monkeypatch.setattr(smoke, 'GRAD_RTOL', float('inf'))
+    with pytest.raises(smoke.SmokeFailure, match='limits are too loose'):
+        if phase == 'lm':
+            smoke.lm_phase(str(tmp_path), TINY, rows=16, batch_size=4,
+                           steps=1)
+        else:
+            smoke.data_parallel_phase(str(tmp_path), TINY, jax.devices()[:4],
+                                      rows=16, global_batch=8, steps=1)
 
 
 def test_refuses_without_tpu(tmp_path):
