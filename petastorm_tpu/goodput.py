@@ -9,9 +9,10 @@ step the consumer loop takes into
     infeed_wait_s = stall_s + h2d_stage_s          (data-path cost)
     train_wall_s  = device_step_s + host_overhead_s (with the opt-in fence)
 
-using the timing sites the loader already owns (``JaxLoaderBase.__iter__``
-times the blocking fetch; ``stage_to_global``/``prefetch_to_device``
-report their staging seconds via :meth:`GoodputMonitor.note_stage`) plus
+using the timing sites the loader already owns (``jax_utils.LoopBoundary``
+times the blocking fetch on the training loop's thread;
+``stage_to_global``/``prefetch_to_device`` report their staging seconds
+via :meth:`GoodputMonitor.note_stage`) plus
 an opt-in ``block_until_ready`` step fence (:meth:`GoodputMonitor.fence`).
 Without the fence the device/host split inside the train wall is unknown
 and the whole wall is attributed to ``device_step`` (recorded as
@@ -97,8 +98,10 @@ class GoodputMonitor:
     """Per-step goodput accounting for one consumer loop.
 
     Constructed by ``JaxLoaderBase`` when :func:`goodput_enabled`; the
-    loader drives :meth:`note_fetch` / :meth:`finish_step` from its
-    ``__iter__`` and the staging sites drive :meth:`note_stage` (possibly
+    loop boundary (``jax_utils.LoopBoundary``) drives :meth:`note_fetch` /
+    :meth:`finish_step` from the training loop's clock readings (under a
+    prefetcher, on the producer thread shortly after) and the
+    staging sites drive :meth:`note_stage` (possibly
     from the prefetch producer thread — the pending accumulators are
     lock-protected; the monitor itself never starts a thread).
 
@@ -135,24 +138,28 @@ class GoodputMonitor:
         # pending state for the step in flight
         self._pending_infeed_s = 0.0
         self._pending_h2d_s = 0.0
-        self._pending_fence_s = 0.0
-        self._pending_fenced = False
+        # fences of the open step: (perf_counter at its end, seconds)
+        self._pending_fences = []
         self._pending_provenance = None
         self._step_open = False
 
     # -- hot-path hooks (loader / staging sites) -------------------------------
 
-    def note_fetch(self, infeed_wait_s: float, batch=None) -> None:
+    def note_fetch(self, infeed_wait_s: float, batch=None, fetched_at=None,
+                   provenance=None) -> None:
         """The loader fetched a batch after blocking ``infeed_wait_s``
-        seconds; opens the step the consumer is about to run."""
-        provenance = None
-        if isinstance(batch, dict):
+        seconds; opens the step the consumer is about to run. A caller that
+        reports the fetch after the fact (``jax_utils.LoopBoundary.fold``)
+        gives its ``time.perf_counter()`` as ``fetched_at``: only fences
+        that ended before it are dropped, not those of the step since. The
+        step's ``provenance`` is ``batch['_provenance']`` unless given."""
+        if provenance is None and isinstance(batch, dict):
             provenance = batch.get('_provenance')
         with self._lock:
             self._pending_infeed_s = max(0.0, float(infeed_wait_s))
             self._pending_provenance = provenance
-            self._pending_fence_s = 0.0
-            self._pending_fenced = False
+            self._pending_fences = [] if fetched_at is None else [
+                f for f in self._pending_fences if f[0] >= fetched_at]
             self._step_open = True
 
     def note_stage(self, elapsed_s: float) -> None:
@@ -171,29 +178,36 @@ class GoodputMonitor:
         import jax
         start = time.perf_counter()
         outputs = jax.block_until_ready(outputs)
-        elapsed = time.perf_counter() - start
+        end = time.perf_counter()
         with self._lock:
-            self._pending_fence_s += elapsed
-            self._pending_fenced = True
+            self._pending_fences.append((end, end - start))
         return outputs
 
-    def finish_step(self, train_wall_s: float) -> Optional[dict]:
+    def finish_step(self, train_wall_s: float,
+                    ended_at=None) -> Optional[dict]:
         """Close the step the consumer just ran (``train_wall_s`` is the
-        yield-to-next-fetch wall the loader measured). Returns the ring
-        entry, or ``None`` when no step was open."""
+        yield-to-next-fetch wall the loader measured). A caller that
+        reports the step after the fact gives its end's
+        ``time.perf_counter()`` as ``ended_at``: fences that ended later
+        belong to the next step. Returns the ring entry, or ``None`` when
+        no step was open."""
         train_wall_s = max(0.0, float(train_wall_s))
         with self._lock:
             if not self._step_open:
                 return None
             infeed = self._pending_infeed_s
             h2d = self._pending_h2d_s
-            fence_s = self._pending_fence_s
-            fenced = self._pending_fenced
+            fences = self._pending_fences
+            if ended_at is not None:
+                self._pending_fences = [f for f in fences if f[0] > ended_at]
+                fences = [f for f in fences if f[0] <= ended_at]
+            else:
+                self._pending_fences = []
+            fence_s = sum(seconds for _, seconds in fences)
+            fenced = bool(fences)
             provenance = self._pending_provenance
             self._pending_infeed_s = 0.0
             self._pending_h2d_s = 0.0
-            self._pending_fence_s = 0.0
-            self._pending_fenced = False
             self._pending_provenance = None
             self._step_open = False
             step = self._steps
@@ -227,19 +241,19 @@ class GoodputMonitor:
             self._h2d_s += h2d_attrib
             self._device_s += device
             self._host_s += host
-        self._record(entry)
+        self._record(entry, ended_at)
         return entry
 
-    def _record(self, entry: dict) -> None:
+    def _record(self, entry: dict, ended_at=None) -> None:
         """Export one closed step to the shared planes (outside the lock:
         stats/tracer take their own locks)."""
         stats = self._stats
         if stats is not None:
-            stats.add_time('goodput_total_s', entry['total_s'])
-            stats.add_time('goodput_stall_s', entry['stall_s'])
-            stats.add_time('goodput_h2d_s', entry['h2d_stage_s'])
-            stats.add_time('goodput_device_s', entry['device_step_s'])
-            stats.add_time('goodput_host_s', entry['host_overhead_s'])
+            stats.merge_times({'goodput_total_s': entry['total_s'],
+                               'goodput_stall_s': entry['stall_s'],
+                               'goodput_h2d_s': entry['h2d_stage_s'],
+                               'goodput_device_s': entry['device_step_s'],
+                               'goodput_host_s': entry['host_overhead_s']})
             stats.record_latency('device_step', entry['device_step_s'])
             if entry['fenced']:
                 stats.record_latency('host_overhead', entry['host_overhead_s'])
@@ -250,7 +264,7 @@ class GoodputMonitor:
                                      entry['host_overhead_s'])
         tracer = self._tracer
         if tracer is not None:
-            now = time.perf_counter()
+            now = time.perf_counter() if ended_at is None else ended_at
             stall_ms = (entry['stall_s'] + entry['h2d_stage_s']) * 1000.0
             tracer.add_span('step', 'goodput', now - entry['total_s'],
                             entry['total_s'],

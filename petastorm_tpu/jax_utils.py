@@ -286,6 +286,147 @@ def pad_ragged_batch(batch, pad_spec):
     return out
 
 
+class LoopBoundary(object):
+    """The training loop's boundary with its input, timed on the thread
+    that waits there: ``infeed_wait`` (the loop blocked for its next batch)
+    and ``train_step`` (the loop's time between two batches).
+
+    The one record site of that boundary, feeding every plane the loader
+    has: ``ReaderStats`` (``infeed_wait_s`` and ``batches_out`` in one
+    update), the tracer's spans, the latency stages and the
+    :class:`~petastorm_tpu.goodput.GoodputMonitor`'s step. Iterating a
+    loader directly records all of it in :class:`LoaderIterator`. Under
+    :func:`prefetch_to_device` / :func:`prefetch_batches` the ring's
+    consumer side reads the clock and makes the stats update (with tracing
+    on, the spans too) on the loop's thread, and the producer thread folds
+    the readings into the latency stages and the goodput step
+    (:meth:`fold`) after each batch it queues: the loop pays two clock
+    reads and one stats update per batch, and those planes lag it by a
+    batch or two until the input ends."""
+
+    __slots__ = ('stats', 'tracer', 'goodput', 'deferred', '_latency',
+                 '_fetch_start', '_step_start', '_readings', '_fold_lock')
+
+    def __init__(self, stats=None, tracer=None, goodput=None):
+        self.stats = stats
+        self.tracer = tracer
+        self.goodput = goodput
+        #: Set by a prefetcher, whose producer thread calls :meth:`fold`.
+        self.deferred = False
+        self._latency = getattr(stats, 'latency', None)
+        self._fetch_start = 0.0
+        self._step_start = None
+        # (step start or None, fetch start, delivery or None, provenance)
+        self._readings = collections.deque()
+        self._fold_lock = threading.Lock()
+
+    def waiting(self):
+        """The loop asks for its next batch: its step in flight ends."""
+        self._fetch_start = time.perf_counter()
+
+    def delivered(self, batch, occupancy=None):
+        """The loop got ``batch``: closes the step that ended when it
+        asked, records the batch's wait (with a prefetcher's
+        ``occupancy``, the batches left in its ring, in the same stats
+        update) and opens the batch's step. Every record comes after both
+        clock reads, so the wait holds none of them."""
+        now = time.perf_counter()
+        step_start, start = self._step_start, self._fetch_start
+        self._step_start = now
+        if self.stats is not None:
+            self.stats.note_batch_out(now - start, occupancy)
+        if self.tracer is not None:
+            if step_start is not None:
+                self.tracer.add_span('train_step', 'consumer', step_start,
+                                     start - step_start)
+            self.tracer.add_span('infeed_wait', 'consumer', start,
+                                 now - start)
+        if self._latency is None and self.goodput is None:
+            return
+        provenance = (batch.get('_provenance')
+                      if self.goodput is not None and isinstance(batch, dict)
+                      else None)
+        self._readings.append((step_start, start, now, provenance))
+        if not self.deferred:
+            self.fold()
+
+    def ended(self):
+        """Closes the step that ended when the loop last asked for a batch
+        (the last one, once the input runs out) and folds every reading."""
+        step_start = self._step_start
+        if step_start is not None:
+            self._step_start = None
+            end = self._fetch_start
+            if self.tracer is not None:
+                self.tracer.add_span('train_step', 'consumer', step_start,
+                                     end - step_start)
+            if self._latency is not None or self.goodput is not None:
+                self._readings.append((step_start, end, None, None))
+        self.fold()
+
+    def fold(self):
+        """Folds the readings taken so far into the latency stages and the
+        goodput step, in the order they were taken."""
+        readings, latency, goodput = self._readings, self._latency, \
+            self.goodput
+        with self._fold_lock:
+            while readings:
+                step_start, start, now, provenance = readings.popleft()
+                if step_start is not None:
+                    if latency is not None:
+                        latency.record('train_step', start - step_start)
+                    if goodput is not None:
+                        goodput.finish_step(start - step_start,
+                                            ended_at=start)
+                if now is None:
+                    continue
+                if latency is not None:
+                    latency.record('infeed_wait', now - start)
+                if goodput is not None:
+                    goodput.note_fetch(now - start, fetched_at=now,
+                                       provenance=provenance)
+
+
+class LoaderIterator(object):
+    """What iterating a loader returns: its batches, with the loop's
+    :class:`LoopBoundary` recorded on the thread that calls ``next``. A
+    prefetcher handed this iterator takes the boundary over
+    (:meth:`take_boundary`) before its producer thread starts drawing."""
+
+    __slots__ = ('loader', '_batches', '_boundary')
+
+    def __init__(self, loader):
+        self.loader = loader
+        self._batches = loader._generate()
+        planes = (loader.stats, loader.tracer, loader.goodput)
+        self._boundary = (LoopBoundary(*planes)
+                          if any(p is not None for p in planes) else None)
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        boundary = self._boundary
+        if boundary is None:
+            return next(self._batches)
+        boundary.waiting()
+        try:
+            batch = next(self._batches)
+        except StopIteration:
+            boundary.ended()
+            raise
+        boundary.delivered(batch)
+        return batch
+
+    def take_boundary(self):
+        """Hands the boundary to the caller; this iterator records no more."""
+        boundary, self._boundary = self._boundary, None
+        return boundary
+
+    def close(self):
+        self._batches.close()
+
+
 class JaxLoaderBase(object):
     """Iteration-state guard + auto-reset, mirroring the reference's
     ``LoaderBase`` (``pytorch.py:104-129``)."""
@@ -295,18 +436,20 @@ class JaxLoaderBase(object):
         self._in_iter = None
         self._error = None
         #: The reader pool's :class:`~petastorm_tpu.tracing.Tracer` (None
-        #: when tracing is off). The iteration loop records ``infeed_wait``
-        #: (time producing the next batch) and ``train_step`` (the consumer's
-        #: gap between batches) spans into it, so the device-idle gap is
-        #: visible on the same timeline as the worker stages.
+        #: when tracing is off). The training loop's :class:`LoopBoundary`
+        #: records ``infeed_wait`` (the loop blocked for its next batch) and
+        #: ``train_step`` (the loop's gap between batches) spans into it, so
+        #: the device-idle gap is visible on the same timeline as the worker
+        #: stages.
         self.tracer = getattr(reader, 'tracer', None)
         #: The reader's :class:`~petastorm_tpu.health.HealthMonitor` (None
-        #: for readers without one). Pass it to ``prefetch_to_device(...,
-        #: health=loader.health)`` so the prefetch thread heartbeats onto the
-        #: same watchdog as the rest of the pipeline.
+        #: for readers without one). A prefetcher handed the loader takes it,
+        #: so the prefetch thread heartbeats onto the same watchdog as the
+        #: rest of the pipeline.
         self.health = getattr(reader, 'health', None)
         #: The reader pool's ``ReaderStats`` (None for readers without one).
-        #: When its latency plane is on, the iteration loop records
+        #: The loop boundary sums ``infeed_wait_s`` and counts
+        #: ``batches_out`` into it, and with its latency plane on records
         #: ``infeed_wait``/``train_step`` duration histograms even with
         #: tracing off — tail latencies must not require a span ring.
         self.stats = getattr(reader, 'stats', None)
@@ -315,7 +458,7 @@ class JaxLoaderBase(object):
         self.prefetch_depth = resolve_prefetch_depth(None)
         #: Per-step goodput accounting
         #: (:class:`~petastorm_tpu.goodput.GoodputMonitor`, None under
-        #: ``PETASTORM_TPU_GOODPUT=0``). The iteration loop feeds it every
+        #: ``PETASTORM_TPU_GOODPUT=0``). The loop boundary feeds it every
         #: step's ``infeed_wait``/train wall; the staging helpers feed it the
         #: H2D dispatch time. Call ``loader.goodput.fence(outputs)`` inside
         #: the step for the exact device/host split (docs/goodput.md).
@@ -335,14 +478,14 @@ class JaxLoaderBase(object):
         assigning ``loader.prefetch_depth`` before calling this
         (docs/readahead.md documents who owns the knob)."""
         if to_device:
-            return prefetch_to_device(iter(self), self.prefetch_depth,
-                                      sharding=sharding, stats=self.stats,
-                                      tracer=self.tracer, health=self.health,
-                                      goodput=self.goodput)
-        return prefetch_batches(iter(self), self.prefetch_depth,
-                                health=self.health, stats=self.stats)
+            return prefetch_to_device(self, self.prefetch_depth,
+                                      sharding=sharding)
+        return prefetch_batches(self, self.prefetch_depth)
 
     def __iter__(self):
+        return LoaderIterator(self)
+
+    def _generate(self):
         if self._error is not None:
             raise RuntimeError('Cannot start a new iteration after a failed one') \
                 from self._error
@@ -353,44 +496,9 @@ class JaxLoaderBase(object):
             logger.warning('Start a new pass of the Reader. To avoid I/O, consider '
                            'in-memory caching (inmemory_cache_all=True).')
         self._in_iter = True
-        tracer = self.tracer
-        goodput = self.goodput
-        latency = getattr(self.stats, 'latency', None) \
-            if self.stats is not None else None
         try:
-            if tracer is None and latency is None and goodput is None:
-                for batch in self._iter_impl():
-                    yield batch
-            else:
-                it = self._iter_impl()
-                fetch_start = time.perf_counter()
-                while True:
-                    try:
-                        batch = next(it)
-                    except StopIteration:
-                        break
-                    now = time.perf_counter()
-                    if latency is not None:
-                        latency.record('infeed_wait', now - fetch_start)
-                    if tracer is not None:
-                        tracer.add_span('infeed_wait', 'consumer',
-                                        fetch_start, now - fetch_start)
-                    if goodput is not None:
-                        goodput.note_fetch(now - fetch_start, batch)
-                    step_start = now
-                    yield batch
-                    # the time the consumer held the generator suspended IS
-                    # its train step (plus any device sync inside it);
-                    # the step's end doubles as the next fetch's start
-                    fetch_start = time.perf_counter()
-                    step_elapsed = fetch_start - step_start
-                    if latency is not None:
-                        latency.record('train_step', step_elapsed)
-                    if tracer is not None:
-                        tracer.add_span('train_step', 'consumer', step_start,
-                                        step_elapsed)
-                    if goodput is not None:
-                        goodput.finish_step(step_elapsed)
+            for batch in self._iter_impl():
+                yield batch
         except Exception as e:
             self._error = e
             raise
@@ -844,7 +952,7 @@ class ShardedJaxLoader(JaxLoaderBase):
         self.prefetch_depth = self._loader.prefetch_depth
         if self.goodput is not None:
             # This loader drives the inner loader's _iter_impl directly,
-            # bypassing its instrumented __iter__ — the OUTER monitor is the
+            # bypassing its loop boundary — the OUTER monitor is the
             # live one. Share it (the staging sites below feed it) and
             # re-register it over the inner loader's dormant registration.
             self._loader.goodput = self.goodput
@@ -987,15 +1095,23 @@ def stage_to_global(batch, named_sharding, stats=None, tracer=None,
     if host:
         device['_host'] = host
     if timed:
-        elapsed = time.perf_counter() - start
-        if stats is not None:
-            stats.add_time('device_stage_s', elapsed)
-            stats.record_latency('device_stage', elapsed)
-        if tracer is not None:
-            tracer.add_span('device_stage', 'device', start, elapsed)
-        if goodput is not None:
-            goodput.note_stage(elapsed)
+        _record_stage(start, stats, tracer, goodput)
     return device
+
+
+def _record_stage(start, stats, tracer, goodput):
+    """The one record site of a staged batch, for both staging helpers:
+    the seconds since ``start`` as ``device_stage_s``, a ``device_stage``
+    latency observation and span, and the goodput step's ``h2d_stage``
+    leg."""
+    elapsed = time.perf_counter() - start
+    if stats is not None:
+        stats.add_time('device_stage_s', elapsed)
+        stats.record_latency('device_stage', elapsed)
+    if tracer is not None:
+        tracer.add_span('device_stage', 'device', start, elapsed)
+    if goodput is not None:
+        goodput.note_stage(elapsed)
 
 
 def infeed_diagnosis(snapshot: dict, heartbeats=None,
@@ -1171,7 +1287,10 @@ def epoch_cache_on_device(loader, sharding=None):
 def prefetch_batches(iterator, size=None, health=None, stats=None):
     """Host-side lookahead WITHOUT device staging: a background thread keeps
     up to ``size`` numpy batches ready; the jitted step's own call performs
-    the host→device transfer. ``health`` (a
+    the host→device transfer. ``iterator`` may be a loader or the iterator
+    ``iter(loader)`` returns: the prefetcher then takes the loader's planes
+    (those not given here) and its :class:`LoopBoundary`, recorded on the
+    consumer's side of the ring. ``health`` (a
     :class:`~petastorm_tpu.health.HealthMonitor`, e.g. ``reader.health``)
     lets the prefetch thread publish liveness heartbeats.
 
@@ -1186,8 +1305,9 @@ def prefetch_batches(iterator, size=None, health=None, stats=None):
     ``prefetch_batches``. ``stats`` (a ``ReaderStats``) gauges the live ring
     depth as ``prefetch_occupancy`` — an empty ring at step boundaries is
     the classic starving signal."""
-    return _pipeline(iterator, resolve_prefetch_depth(size),
-                     lambda batch: batch, health=health, stats=stats)
+    source = _Source(iterator, stats=stats, health=health)
+    return _pipeline(source, resolve_prefetch_depth(size),
+                     lambda batch: batch)
 
 
 def prefetch_to_device(iterator, size=None, sharding=None, stats=None,
@@ -1197,10 +1317,17 @@ def prefetch_to_device(iterator, size=None, sharding=None, stats=None,
     Stages up to ``size`` batches ahead of the consumer on a background thread
     so the ``jax.device_put`` (host→HBM DMA) of batch N+1 overlaps the compute
     of batch N. When batches are already global ``jax.Array``s (from
-    ``ShardedJaxLoader``) the transfer has been issued at construction time and
-    this just provides pipelining depth. See :func:`prefetch_batches` for the
+    ``ShardedJaxLoader``) the transfer has been issued, and recorded, at
+    construction time: this just provides pipelining depth and records no
+    second ``device_stage``. See :func:`prefetch_batches` for the
     small-batch/latency-bound alternative.
 
+    :param iterator: the batches: a loader, the iterator ``iter(loader)``
+        returns, or any iterator of batch pytrees. From a loader the
+        prefetcher takes the planes below that are not given, and its
+        :class:`LoopBoundary`: the loop's ``infeed_wait``/``train_step``
+        are then recorded on the consumer's side of the ring, and the
+        producer thread's time inside the loader is a ``stage_next`` span.
     :param sharding: optional ``jax.sharding.Sharding`` applied via
         ``jax.device_put`` to plain numpy batches.
     :param stats: optional ``ReaderStats`` (e.g. ``reader.stats`` /
@@ -1227,11 +1354,18 @@ def prefetch_to_device(iterator, size=None, sharding=None, stats=None,
     """
     import jax
     size = resolve_prefetch_depth(size)
+    source = _Source(iterator, stats=stats, tracer=tracer, health=health,
+                     goodput=goodput)
+    stats, tracer, goodput = source.stats, source.tracer, source.goodput
 
     def put(batch):
         # _is_device_compatible reads dtype via getattr: global jax.Arrays must
         # NOT be round-tripped through np.asarray (device->host copy; crashes
         # on non-fully-addressable multi-host arrays).
+        if sharding is None and fused_fn is None and all(
+                isinstance(x, jax.Array) or not _is_device_compatible(x)
+                for x in jax.tree_util.tree_leaves(batch)):
+            return batch    # staged, and recorded, where it was assembled
         timed = stats is not None or tracer is not None or goodput is not None
         start = time.perf_counter() if timed else 0.0
         if sharding is None:
@@ -1251,31 +1385,77 @@ def prefetch_to_device(iterator, size=None, sharding=None, stats=None,
                 staged = dict(fused_fn(dev))
                 staged.update(host)
         if timed:
-            elapsed = time.perf_counter() - start
-            if stats is not None:
-                stats.add_time('device_stage_s', elapsed)
-                stats.record_latency('device_stage', elapsed)
-            if tracer is not None:
-                tracer.add_span('device_stage', 'device', start, elapsed)
-            if goodput is not None:
-                goodput.note_stage(elapsed)
+            _record_stage(start, stats, tracer, goodput)
         return staged
 
-    return _pipeline(iterator, size, put, health=health, stats=stats)
+    return _pipeline(source, size, put)
 
 
-def _pipeline(iterator, size, put, health=None, stats=None):
-    """Shared producer-thread pipeline behind the two prefetchers.
-    ``stats`` gauges the ring's live depth as ``prefetch_occupancy`` on
-    every enqueue/dequeue — the depth is read under the ring's condition
-    but the gauge is recorded OUTSIDE it (the stats lock must never nest
-    inside the ring lock)."""
+class _Source(object):
+    """A prefetcher's source and planes. Handed a loader or its
+    :class:`LoaderIterator`, it takes the loader's planes where none is
+    given, and the loop boundary, which the ring's consumer side times and
+    the producer thread folds (:meth:`LoopBoundary.fold`); the producer
+    thread's time inside the loader is then a ``stage_next`` span."""
+
+    __slots__ = ('batches', 'boundary', 'stats', 'tracer', 'health',
+                 'goodput')
+
+    def __init__(self, iterator, stats=None, tracer=None, health=None,
+                 goodput=None):
+        if isinstance(iterator, JaxLoaderBase):
+            iterator = iter(iterator)
+        self.boundary = None
+        if isinstance(iterator, LoaderIterator):
+            loader = iterator.loader
+            stats = loader.stats if stats is None else stats
+            tracer = loader.tracer if tracer is None else tracer
+            health = loader.health if health is None else health
+            goodput = loader.goodput if goodput is None else goodput
+            self.boundary = iterator.take_boundary()
+            if self.boundary is not None:
+                self.boundary.deferred = True
+            if tracer is not None:
+                iterator = _spanned(iterator, tracer)
+        self.batches = iterator
+        self.stats, self.tracer = stats, tracer
+        self.health, self.goodput = health, goodput
+
+
+def _spanned(batches, tracer):
+    """``batches``, each draw recorded as a ``stage_next`` span on the
+    drawing (producer) thread."""
+    batches = iter(batches)
+    while True:
+        start = time.perf_counter()
+        try:
+            batch = next(batches)
+        except StopIteration:
+            return
+        tracer.add_span('stage_next', 'producer', start,
+                        time.perf_counter() - start)
+        yield batch
+
+
+def _pipeline(source, size, put):
+    """Shared producer-thread pipeline behind the two prefetchers, drawing
+    from ``source`` (a :class:`_Source`). Its ``stats`` gauges the ring's
+    live depth as ``prefetch_occupancy`` on every enqueue/dequeue — the
+    depth is read under the ring's condition but the gauge is recorded
+    OUTSIDE it (the stats lock must never nest inside the ring lock). Its
+    ``boundary``, when the source is a loader, is timed here on the
+    consumer's side, the thread that waits for the batch, and folded on
+    the producer's."""
     queue = collections.deque()
     done = object()
     cv = threading.Condition()
     state = {'error': None, 'finished': False}
-    beat = health.beat if health is not None else None
-    gauge = stats.gauge if stats is not None else None
+    iterator, boundary = source.batches, source.boundary
+    beat = source.health.beat if source.health is not None else None
+    gauge = source.stats.gauge if source.stats is not None else None
+    # the boundary's stats update carries the consumer side's gauge
+    folded = (boundary is not None and gauge is not None
+              and boundary.stats is source.stats)
 
     def producer():
         try:
@@ -1299,6 +1479,8 @@ def _pipeline(iterator, size, put, health=None, stats=None):
                     cv.notify_all()
                 if gauge is not None:
                     gauge('prefetch_occupancy', depth)
+                if boundary is not None:
+                    boundary.fold()
                 if beat is not None:
                     beat('loader-prefetch', 'idle')
         except Exception as e:  # propagate into the consumer
@@ -1315,6 +1497,8 @@ def _pipeline(iterator, size, put, health=None, stats=None):
     thread.start()
     try:
         while True:
+            if boundary is not None:
+                boundary.waiting()
             with cv:
                 while not queue:
                     cv.wait()
@@ -1324,10 +1508,14 @@ def _pipeline(iterator, size, put, health=None, stats=None):
                 depth = len(queue) - (1 if queue and queue[-1] is done else 0)
                 cv.notify_all()
             if item is done:
+                if boundary is not None:
+                    boundary.ended()
                 if state['error'] is not None:
                     raise state['error']
                 return
-            if gauge is not None:
+            if boundary is not None:
+                boundary.delivered(item, depth if folded else None)
+            if gauge is not None and not folded:
                 gauge('prefetch_occupancy', depth)
             yield item
     finally:
@@ -1335,3 +1523,5 @@ def _pipeline(iterator, size, put, health=None, stats=None):
             state['finished'] = True
             queue.clear()
             cv.notify_all()
+        if boundary is not None:
+            boundary.fold()

@@ -351,7 +351,8 @@ def build_fused_infeed(plans: Dict[str, DeviceColumnPlan],
     device-compatible arrays (the caller keeps host-only columns out and
     merges them back; ``stage_to_global`` / ``prefetch_to_device`` /
     ``JaxDataLoader`` all share this builder so the three call sites
-    cannot drift)."""
+    cannot drift). It compiles as :data:`DEVICE_DECODE_PROGRAM`: a
+    profiler trace names its runs ``jit_petastorm_device_decode``."""
     import jax
     plans = dict(plans)
     func = None
@@ -359,7 +360,7 @@ def build_fused_infeed(plans: Dict[str, DeviceColumnPlan],
                                               None) is not None:
         func = transform_spec.func
 
-    def _fused(columns):
+    def petastorm_device_decode(columns):
         out = dict(columns)
         for name, plan in plans.items():
             if name in out:
@@ -368,7 +369,13 @@ def build_fused_infeed(plans: Dict[str, DeviceColumnPlan],
             out = func(out)
         return out
 
-    return jax.jit(_fused)
+    return jax.jit(petastorm_device_decode)
+
+
+#: The name the fused infeed program compiles under (its ``XLA Modules``
+#: events read ``jit_`` and this name), kept stable so a trace reduction
+#: finds the staging layer's device work.
+DEVICE_DECODE_PROGRAM = 'petastorm_device_decode'
 
 
 def split_device_columns(batch, plans: Dict[str, DeviceColumnPlan],

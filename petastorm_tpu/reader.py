@@ -577,9 +577,9 @@ class Reader:
                                 type(cache).__name__, 'null')
         #: The pipeline's :class:`~petastorm_tpu.health.HealthMonitor`:
         #: per-entity heartbeats from the ventilator, the pool's workers
-        #: (plus their readahead threads), and — when wired via
-        #: ``prefetch_to_device(..., health=...)`` — the loader's prefetch
-        #: thread. ``reader.health.heartbeats()`` is the live record set.
+        #: (plus their readahead threads), and — when a prefetcher is handed
+        #: the loader, or given ``health=`` — the loader's prefetch thread.
+        #: ``reader.health.heartbeats()`` is the live record set.
         self.health = HealthMonitor()
 
         filesystem = filesystem_factory()

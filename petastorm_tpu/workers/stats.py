@@ -32,12 +32,17 @@ TIME_STAGES = (
     'readahead_wait_s',  # worker blocked on a prefetched-but-unfinished read
                          # (the un-hidden part of readahead_io_s; also
                          # counted in worker_io_s)
-    'worker_decode_s',   # codec decode / transform inside the worker
+    'worker_decode_s',   # codec decode / transform inside the worker:
+                         # process() wall less io and transport, so with
+                         # thread workers it counts GIL waits as decode
     'worker_publish_wait_s',  # worker blocked on a full results queue
     'serialize_s',       # payload -> transport frames (process pools)
     'deserialize_s',     # transport frames -> payload (consumer side)
     'queue_wait_s',      # consumer blocked waiting for a result
     'device_stage_s',    # host -> device transfer (jax loaders)
+    'infeed_wait_s',     # the training loop blocked for its next batch, on
+                         # the loop's thread (jax_utils.LoopBoundary; under
+                         # a prefetcher its consumer side)
     # goodput plane (docs/goodput.md): per-training-step decomposition summed
     # by the loader's GoodputMonitor. Additive seconds — pod aggregation sums
     # them and re-derives the fractions, never averages fractions.
@@ -56,6 +61,8 @@ COUNTERS = (
     'payload_copies',    # full-payload memcpys made by the transport
     'payload_frames',    # transport frames shipped (multipart parts)
     'items_out',         # results delivered to the consumer
+    'batches_out',       # batches handed to the training loop, counted on
+                         # the loop's thread with infeed_wait_s
     'readahead_hits',    # row-group reads served from the prefetch queue
     'readahead_misses',  # row-group reads that went inline (not prefetched)
     'rows_quarantined',  # rows dropped under on_decode_error='skip'/'quarantine'
@@ -229,6 +236,19 @@ class ReaderStats:
             key = name + '_max'
             if value > self._gauges.get(key, 0):
                 self._gauges[key] = value
+
+    def note_batch_out(self, infeed_wait_s: float, occupancy=None) -> None:
+        """One batch handed to the training loop after it blocked
+        ``infeed_wait_s`` seconds: ``infeed_wait_s`` and ``batches_out``,
+        and, from a prefetcher, the ring's ``prefetch_occupancy`` left
+        behind, in one update."""
+        with self._lock:
+            self._times['infeed_wait_s'] += infeed_wait_s
+            self._counts['batches_out'] += 1
+            if occupancy is not None:
+                self._gauges['prefetch_occupancy'] = occupancy
+                if occupancy > self._gauges['prefetch_occupancy_max']:
+                    self._gauges['prefetch_occupancy_max'] = occupancy
 
     @contextmanager
     def timed(self, stage: str):

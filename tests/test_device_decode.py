@@ -19,7 +19,8 @@ from petastorm_tpu.jax_utils import (DEFAULT_PREFETCH_DEPTH,
                                      PREFETCH_DEPTH_ENV_VAR, JaxDataLoader,
                                      _contiguous_rows_view, infeed_diagnosis,
                                      make_jax_loader, resolve_prefetch_depth)
-from petastorm_tpu.ops.decode import (DEVICE_DECODE_ENV_VAR, DeviceColumnPlan,
+from petastorm_tpu.ops.decode import (DEVICE_DECODE_ENV_VAR,
+                                      DEVICE_DECODE_PROGRAM, DeviceColumnPlan,
                                       build_fused_infeed, decode_raw_host,
                                       decode_raw_jax, device_decode_enabled,
                                       npy_header_bytes, plan_device_decode,
@@ -306,6 +307,16 @@ class TestFusedInfeed:
         expect = np.stack(values) * 2
         assert bool(np.array_equal(np.asarray(out['tokens']), expect))
 
+    def test_program_compiles_under_a_stable_name(self):
+        """A profiler trace names the fused program's runs by this name, so
+        a trace reduction finds the staging layer's device work."""
+        plan, _ = plan_for_field(_field('tokens', np.int32, (4,)))
+        raw = np.zeros((2, plan.stride), dtype=np.uint8)
+        text = build_fused_infeed({'tokens': plan}).lower(
+            {'tokens': raw}).as_text()
+        assert DEVICE_DECODE_PROGRAM == 'petastorm_device_decode'
+        assert 'jit_petastorm_device_decode' in text
+
     def test_split_routes_only_planned_columns_by_default(self):
         """Unplanned columns must stay host numpy: silently returning them
         as immutable jax.Arrays breaks consumers that mutate in place."""
@@ -545,6 +556,34 @@ class TestShardedLoader:
         assert bool(np.array_equal(got, baseline))
         assert snapshot['rows_decoded_device'] == len(got)
         assert snapshot['device_decode_fraction'] == 1.0
+
+    def test_prefetched_global_batches_stage_once(self, token_store,
+                                                 monkeypatch):
+        """The four-chip cell's feed: ``ShardedJaxLoader`` under
+        ``prefetch_to_device(iter(loader))``. Each batch is staged and
+        decoded once, in ``stage_to_global``; the prefetcher has nothing
+        left to transfer and records no second ``device_stage``."""
+        from petastorm_tpu.jax_utils import (ShardedJaxLoader,
+                                             prefetch_to_device)
+        from jax.sharding import Mesh
+        monkeypatch.setenv(DEVICE_DECODE_ENV_VAR, 'on')
+        mesh = Mesh(np.array(jax.devices()[:4]), ('data',))
+        with make_columnar_reader(token_store, num_epochs=1,
+                                  workers_count=1, shuffle_row_groups=False,
+                                  trace=True) as reader:
+            with ShardedJaxLoader(reader, mesh, local_batch_size=8) as loader:
+                batches = list(prefetch_to_device(iter(loader), size=2))
+                steps = loader.goodput.summary()['steps']
+            snapshot = reader.stats.snapshot()
+            stages = [s for s in reader.tracer.spans()
+                      if s[0] == 'device_stage']
+            latency = reader.stats.latency.histograms['device_stage'].count
+        assert len(batches) == 8
+        assert all(len(b['tokens'].sharding.device_set) == 4
+                   for b in batches)
+        assert len(stages) == latency == snapshot['batches_out'] == 8
+        assert steps == 8
+        assert snapshot['rows_decoded_device'] == 64
 
     def test_transform_fn_declines_claim_and_sees_decoded_numpy(
             self, token_store, monkeypatch):

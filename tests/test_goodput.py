@@ -130,6 +130,28 @@ class TestDecomposition:
         assert entry['device_step_s'] == 0.5
         assert entry['host_overhead_s'] == 0.0
 
+    def test_a_late_report_keeps_each_fence_with_its_step(self):
+        """A prefetcher reports the loop's steps after the fact, with the
+        times the loop read: a fence counts in the step it ran in, even if
+        the report comes after the next step's fence, and one taken while
+        the loop waited for a batch counts in none."""
+        import time
+        monitor = GoodputMonitor()
+        fetched_1 = time.perf_counter()
+        monitor.fence(np.zeros(1))          # inside step 1
+        ended_1 = time.perf_counter()
+        monitor.fence(np.zeros(1))          # while the loop waits
+        fetched_2 = time.perf_counter()
+        monitor.fence(np.zeros(1))          # inside step 2
+        monitor.note_fetch(0.0, fetched_at=fetched_1)
+        first = monitor.finish_step(ended_1 - fetched_1, ended_at=ended_1)
+        monitor.note_fetch(0.0, fetched_at=fetched_2)
+        assert len(monitor._pending_fences) == 1
+        second = monitor.finish_step(0.5)
+        assert first['fenced'] and second['fenced']
+        assert monitor.state()['fenced_steps'] == 2
+        assert monitor._pending_fences == []
+
     def test_finish_without_open_step_is_none(self):
         monitor = GoodputMonitor()
         assert monitor.finish_step(0.5) is None
@@ -554,6 +576,26 @@ class TestLoaderIntegration:
                 summary = loader.goodput.summary()
         assert summary['fenced_steps'] >= 1
         assert summary['fenced_steps'] <= summary['steps']
+
+    def test_fence_inside_a_prefetched_loop_fences_every_step(
+            self, token_store, monkeypatch):
+        """Under a prefetcher the producer thread folds the loop's steps
+        into the monitor after the loop has moved on: every fence still
+        lands in its own step, and every step is in by the end."""
+        from petastorm_tpu.jax_utils import JaxDataLoader, prefetch_to_device
+        from petastorm_tpu.reader import make_columnar_reader
+        monkeypatch.delenv(GOODPUT_ENV_VAR, raising=False)
+        with make_columnar_reader(token_store, num_epochs=1, workers_count=1,
+                                  shuffle_row_groups=False) as reader:
+            with JaxDataLoader(reader, batch_size=16) as loader:
+                count = 0
+                for batch in prefetch_to_device(loader, size=2):
+                    loader.goodput.fence(batch['tokens'])
+                    count += 1
+                state = loader.goodput.state()
+            waits = reader.stats.latency.histograms['infeed_wait'].count
+        assert count == 3
+        assert state['steps'] == state['fenced_steps'] == waits == count
 
     def test_device_decode_off_interplay(self, token_store, monkeypatch):
         """PETASTORM_TPU_DEVICE_DECODE=off must not take the goodput plane

@@ -9,7 +9,8 @@ instead of TF/torch adapters.
 import numpy as np
 import pytest
 
-from petastorm_tpu.jax_utils import (JaxDataLoader, make_jax_loader,
+from petastorm_tpu.jax_utils import (JaxDataLoader, LoaderIterator,
+                                     make_jax_loader, prefetch_batches,
                                      prefetch_to_device, sanitize_jax_types)
 from petastorm_tpu.reader import make_batch_reader, make_reader
 
@@ -199,6 +200,60 @@ class TestPrefetch:
             direct = _all_ids(list(loader))
             prefetched = _all_ids(list(prefetch_to_device(iter(loader), size=2)))
         assert direct == prefetched
+
+    @pytest.mark.parametrize('feed', [
+        lambda loader: iter(loader),
+        lambda loader: prefetch_to_device(loader, size=2),
+        lambda loader: prefetch_to_device(iter(loader), size=2),
+        lambda loader: loader.iter_prefetched(),
+        lambda loader: prefetch_batches(loader, size=2),
+    ], ids=['direct', 'to_device(loader)', 'to_device(iter(loader))',
+            'iter_prefetched', 'batches(loader)'])
+    def test_loop_boundary_is_recorded_where_the_loop_waits(
+            self, scalar_dataset, feed):
+        """A slow producer: ``infeed_wait_s`` is the wait the loop saw,
+        ``batches_out`` the batches it got, and the ``infeed_wait`` /
+        ``train_step`` spans carry the loop's thread. A prefetcher's
+        producer thread records none of them, only its ``stage_next``."""
+        import threading
+        import time
+
+        def slow(batch):
+            time.sleep(0.01)
+            return batch
+
+        with make_batch_reader(scalar_dataset.url, reader_pool_type='dummy',
+                               num_epochs=1, trace=True) as reader:
+            loader = JaxDataLoader(reader, batch_size=8, transform_fn=slow)
+            batches = feed(loader)
+            got, waited = 0, 0.0
+            while True:
+                start = time.perf_counter()
+                try:
+                    next(batches)
+                except StopIteration:
+                    break
+                waited += time.perf_counter() - start
+                got += 1
+            snapshot = reader.stats.snapshot()
+            spans = reader.tracer.spans()
+        loop = threading.get_ident()
+        assert got >= 3 and snapshot['batches_out'] == got
+        assert 0.9 * waited <= snapshot['infeed_wait_s'] <= waited
+        assert snapshot['infeed_wait_s'] >= 0.005 * got
+        boundary = [s for s in spans if s[0] in ('infeed_wait', 'train_step')]
+        assert {s[5] for s in boundary} == {loop}
+        assert sum(1 for s in boundary if s[0] == 'infeed_wait') == got
+        assert sum(1 for s in boundary if s[0] == 'train_step') == got
+        # the goodput step spans end where the loop's steps ended, even
+        # when the producer thread folded them a batch later
+        step_ends = sorted(s[2] + s[3] for s in boundary
+                           if s[0] == 'train_step')
+        assert sorted(s[2] + s[3] for s in spans if s[0] == 'step') == \
+            pytest.approx(step_ends, abs=1e-6)
+        producer = {s[5] for s in spans if s[0] == 'stage_next'}
+        assert loop not in producer
+        assert bool(producer) != isinstance(batches, LoaderIterator)
 
     def test_prefetch_propagates_errors(self):
         def boom():

@@ -60,6 +60,19 @@ class TestReaderStatsUnit:
         assert snap['queue_depth'] == 3          # last sample
         assert snap['queue_depth_max'] == 7      # high-water mark
 
+    def test_batch_out_sums_the_wait_and_carries_the_ring_gauge(self):
+        """The training loop's one stats update per batch: its wait, the
+        batch count and, from a prefetcher, the ring's occupancy."""
+        stats = ReaderStats()
+        stats.note_batch_out(0.5, 3)
+        stats.note_batch_out(0.25, 1)
+        stats.note_batch_out(0.25)
+        snap = stats.snapshot()
+        assert snap['infeed_wait_s'] == pytest.approx(1.0)
+        assert snap['batches_out'] == 3
+        assert snap['prefetch_occupancy'] == 1   # last sample; None keeps it
+        assert snap['prefetch_occupancy_max'] == 3
+
     def test_timed_context_and_merge(self):
         stats = ReaderStats()
         with stats.timed('deserialize_s'):
