@@ -420,16 +420,10 @@ def _segment_positions(segment_ids):
     return idx - starts
 
 
-def forward(params, tokens, config: TransformerConfig,
-            positions: Optional[jnp.ndarray] = None, mesh=None,
-            return_aux: bool = False, segment_ids=None):
-    """tokens (B, L) int32 → logits (B, L, vocab) float32.
-
-    With ``return_aux=True`` also returns the summed MoE load-balancing
-    auxiliary loss (0.0 for dense models). ``segment_ids`` (B, L) enables
-    packed multi-document batches (see ``petastorm_tpu.packing``): attention
-    is masked to same-segment pairs — pass the packer's per-document
-    ``positions`` too so rotary offsets restart per document."""
+def _hidden(params, tokens, config: TransformerConfig, positions, mesh,
+            segment_ids):
+    """tokens (B, L) → the final-normed hidden states (B, L, D) in the
+    compute dtype, and the summed MoE aux loss (see :func:`forward`)."""
     c = config
     if positions is None:
         if segment_ids is not None:
@@ -451,9 +445,48 @@ def forward(params, tokens, config: TransformerConfig,
             aux_total = aux_total + aux
         else:
             x = x + _dense_ffn(h, layer)
-    x = _rms_norm(x, params['final_norm'])
-    logits = (x @ params['unembed'].astype(c.dtype)).astype(jnp.float32)
+    return _rms_norm(x, params['final_norm']), aux_total
+
+
+def forward(params, tokens, config: TransformerConfig,
+            positions: Optional[jnp.ndarray] = None, mesh=None,
+            return_aux: bool = False, segment_ids=None):
+    """tokens (B, L) int32 → logits (B, L, vocab) float32.
+
+    With ``return_aux=True`` also returns the summed MoE load-balancing
+    auxiliary loss (0.0 for dense models). ``segment_ids`` (B, L) enables
+    packed multi-document batches (see ``petastorm_tpu.packing``): attention
+    is masked to same-segment pairs — pass the packer's per-document
+    ``positions`` too so rotary offsets restart per document."""
+    x, aux_total = _hidden(params, tokens, config, positions, mesh,
+                           segment_ids)
+    logits = (x @ params['unembed'].astype(config.dtype)).astype(jnp.float32)
     return (logits, aux_total) if return_aux else logits
+
+
+@jax.custom_vjp
+def _cross_entropy(logits, targets):
+    """Per-position ``-log softmax(logits)[target]`` in float32, from logits
+    in the compute dtype: the float32 arithmetic stays inside the
+    reductions and the backward, so no float32 copy of the (B, L, vocab)
+    logits is ever written."""
+    return _cross_entropy_fwd(logits, targets)[0]
+
+
+def _cross_entropy_fwd(logits, targets):
+    lse = jax.nn.logsumexp(logits.astype(jnp.float32), axis=-1)
+    tgt = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    return lse - tgt.astype(jnp.float32), (logits, lse, targets)
+
+
+def _cross_entropy_bwd(res, g):
+    logits, lse, targets = res
+    probs = jnp.exp(logits.astype(jnp.float32) - lse[..., None])
+    onehot = jax.nn.one_hot(targets, logits.shape[-1], dtype=jnp.float32)
+    return ((probs - onehot) * g[..., None]).astype(logits.dtype), None
+
+
+_cross_entropy.defvjp(_cross_entropy_fwd, _cross_entropy_bwd)
 
 
 def loss_fn(params, tokens, targets, config: TransformerConfig, mesh=None,
@@ -467,11 +500,8 @@ def loss_fn(params, tokens, targets, config: TransformerConfig, mesh=None,
     ``packed_lm_targets`` — attention is segment-masked, rotary offsets
     restart per document, and padding/document-boundary slots get zero loss
     weight (mean over weighted slots only)."""
-    logits, aux = forward(params, tokens, config, positions=positions,
-                          mesh=mesh, return_aux=True,
-                          segment_ids=segment_ids)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1).squeeze(-1)
+    x, aux = _hidden(params, tokens, config, positions, mesh, segment_ids)
+    nll = _cross_entropy(x @ params['unembed'].astype(config.dtype), targets)
     if weights is None:
         loss = jnp.mean(nll)
     else:

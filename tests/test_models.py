@@ -163,6 +163,85 @@ class TestTransformerLm:
                                        jnp.roll(toks, -1, axis=1))
         assert np.isfinite(float(loss))
 
+    # grad_rtol bounds the worst leaf's gradient gap over the leaf's norm.
+    # In bf16 both sides round the same float32 softmax gradient to bf16, and
+    # float32 rounding order can move one value by one bf16 step (2**-8).
+    @pytest.mark.parametrize('kw,axes,packed,grad_rtol', [
+        ({}, None, False, 1e-6),
+        ({'dtype': jnp.bfloat16}, None, False, 2.0 ** -8),
+        ({}, None, True, 1e-6),
+        ({'n_experts': 2}, None, False, 1e-6),
+        ({}, {'data': 2, 'model': 2}, False, 1e-6),
+    ], ids=['f32', 'bf16', 'packed', 'moe_aux', 'dp2_tp2'])
+    def test_loss_matches_log_softmax(self, cpus, kw, axes, packed,
+                                      grad_rtol):
+        """loss_fn's value and gradient equal the plain float32
+        ``-take_along_axis(log_softmax(logits))`` over forward's logits, on
+        the same devices (on the mesh, with the vocab axis sharded)."""
+        from jax.sharding import NamedSharding, PartitionSpec
+        from petastorm_tpu.models import transformer_lm as tlm
+        from petastorm_tpu.packing import pack_documents, packed_lm_targets
+        from petastorm_tpu.parallel import make_mesh
+
+        cfg = _tiny_config(vocab_size=256, **kw)
+        rng = np.random.default_rng(7)
+        extra = {}
+        if packed:
+            batch = pack_documents(
+                [list(rng.integers(0, 256, n)) for n in (7, 5, 9, 11)],
+                seq_len=32, num_rows=2)
+            toks = jnp.asarray(batch.tokens, jnp.int32)
+            seg = jnp.asarray(batch.segment_ids)
+            tgts, weights = packed_lm_targets(toks, seg)
+            extra = dict(positions=jnp.asarray(batch.positions),
+                         segment_ids=seg, weights=weights)
+        else:
+            toks = jnp.asarray(rng.integers(0, 256, (4, 32)), jnp.int32)
+            tgts = jnp.roll(toks, -1, axis=1)
+        with jax.default_device(cpus[0]):
+            params = tlm.init(jax.random.PRNGKey(3), cfg)
+
+        mesh = None
+        if axes is not None:
+            mesh = make_mesh(axes, devices=cpus[:4])
+            pshard = jax.tree_util.tree_map(
+                lambda s: NamedSharding(mesh, s), tlm.param_specs(cfg, mesh),
+                is_leaf=lambda x: isinstance(x, PartitionSpec))
+            params = jax.tree_util.tree_map(jax.device_put, params, pshard)
+            bshard = NamedSharding(mesh, tlm.batch_spec(mesh))
+            toks, tgts = (jax.device_put(a, bshard) for a in (toks, tgts))
+            assert params['unembed'].sharding.spec == PartitionSpec(None,
+                                                                    'model')
+
+        def reference(params, toks, tgts):
+            logits, aux = tlm.forward(
+                params, toks, cfg, positions=extra.get('positions'),
+                mesh=mesh, return_aux=True,
+                segment_ids=extra.get('segment_ids'))
+            assert logits.dtype == jnp.float32
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, tgts[..., None], axis=-1)[..., 0]
+            if packed:
+                w = extra['weights']
+                loss = jnp.sum(nll * w) / jnp.maximum(jnp.sum(w), 1.0)
+            else:
+                loss = jnp.mean(nll)
+            return loss + (cfg.moe_aux_weight * aux if cfg.n_experts else 0.0)
+
+        want, want_grads = jax.jit(jax.value_and_grad(reference))(
+            params, toks, tgts)
+        got, grads = jax.jit(jax.value_and_grad(
+            lambda p, x, y: tlm.loss_fn(p, x, y, cfg, mesh, **extra)))(
+                params, toks, tgts)
+
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+        gaps = jax.tree_util.tree_map(
+            lambda a, b: float(np.linalg.norm(np.asarray(a, np.float64)
+                                              - np.asarray(b, np.float64))
+                               / np.linalg.norm(np.asarray(b, np.float64))),
+            grads, want_grads)
+        assert max(jax.tree_util.tree_leaves(gaps)) <= grad_rtol, gaps
+
 
 class TestGenerate:
     @pytest.mark.parametrize('kw', [

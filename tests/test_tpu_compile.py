@@ -107,3 +107,37 @@ def test_ring_attention_data_seq_mesh(topo):
     hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), *_qkv(sharding))
     assert 'tpu_custom_call' in hlo
     assert 'collective-permute' in hlo
+
+
+def test_lm_loss_writes_no_float32_logits(topo):
+    """The LM's loss and gradient on a data-parallel mesh write no float32
+    copy of the per-chip (B, L, vocab) logits: the cross-entropy reads the
+    bf16 logits and keeps its float32 arithmetic inside its fusions."""
+    from petastorm_tpu.models import transformer_lm as tlm
+    from petastorm_tpu.parallel import make_mesh
+
+    cfg = tlm.TransformerConfig(vocab_size=50304, d_model=256, n_heads=4,
+                                n_layers=2, d_ff=1024, max_seq_len=512,
+                                attention='flash')
+    batch, seq, chips = 16, 512, 4
+    mesh = make_mesh({'data': chips}, devices=topo.devices)
+    shapes = jax.eval_shape(lambda: tlm.init(jax.random.PRNGKey(0), cfg))
+    params = jax.tree_util.tree_map(
+        lambda s, spec: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, tlm.param_specs(cfg, mesh),
+        is_leaf=lambda x: isinstance(x, P))
+    tokens = jax.ShapeDtypeStruct(
+        (batch, seq), jnp.int32,
+        sharding=NamedSharding(mesh, tlm.batch_spec(mesh)))
+
+    hlo = _hlo(jax.value_and_grad(
+        lambda p, x, y: tlm.loss_fn(p, x, y, cfg, mesh)), params, tokens,
+        tokens)
+    logits_f32 = 'f32[{},{},{}]'.format(batch // chips, seq, cfg.vocab_size)
+    # a fusion's output types stand between '=' and its opcode
+    written = [line.strip() for line in hlo.splitlines()
+               if ' fusion(' in line
+               and logits_f32 in line.split(' fusion(')[0].split(' = ')[-1]]
+    assert 'tpu_custom_call' in hlo
+    assert not written, written
