@@ -677,22 +677,63 @@ def generate(params, tokens, config: TransformerConfig, max_new_tokens: int,
 # training
 # ---------------------------------------------------------------------------
 
+class _MeshStep:
+    """The jitted mesh step, called as ``make_train_step`` documents. With
+    ``in_shardings`` jit takes positional arguments only and as many as
+    there are shardings, so the unpacked and the packed call each have a
+    jit of the one step function; ``lower`` follows ``__call__``."""
+
+    def __init__(self, unpacked, packed):
+        self._unpacked, self._packed = unpacked, packed
+
+    def _route(self, name, params, opt_state, tokens, targets=None,
+               segment_ids=None, positions=None):
+        if segment_ids is None:
+            return getattr(self._unpacked, name)(params, opt_state, tokens,
+                                                 targets)
+        return getattr(self._packed, name)(params, opt_state, tokens, targets,
+                                           segment_ids, positions)
+
+    def __call__(self, *args, **kwargs):
+        return self._route('__call__', *args, **kwargs)
+
+    def lower(self, *args, **kwargs):
+        return self._route('lower', *args, **kwargs)
+
+
 def make_train_step(config: TransformerConfig, mesh=None, optimizer=None):
     """Build a jitted ``(params, opt_state, tokens, targets) -> (params,
     opt_state, loss)`` step.
 
+    Packed batches (``petastorm_tpu.packing``) call it as ``step(params,
+    opt_state, tokens, segment_ids=..., positions=...)``, with no targets:
+    the step trains on ``packing.packed_lm_targets``' next-token targets and
+    weights (0 at each document's last token and on padding), its loss the
+    mean over weighted slots. Without them it is the unpacked step.
+
     With ``mesh``, params/activations are constrained to :func:`param_specs` /
-    :func:`batch_spec` shardings (dp/tp/sp/ep as present in the mesh); ring
-    and flash attention additionally run under shard_map, and the returned
-    optimizer's ``init`` builds its state on the mesh.
+    :func:`batch_spec` shardings (dp/tp/sp/ep as present in the mesh; the
+    packed columns take the batch's); ring and flash attention additionally
+    run under shard_map, and the returned optimizer's ``init`` builds its
+    state on the mesh.
     """
     import optax
+
+    from petastorm_tpu.packing import packed_lm_targets
     if optimizer is None:
         optimizer = optax.adamw(3e-4, weight_decay=0.01)
 
-    def step(params, opt_state, tokens, targets):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, targets,
-                                                  config, mesh)
+    def step(params, opt_state, tokens, targets=None, segment_ids=None,
+             positions=None):
+        weights = None
+        if segment_ids is not None:
+            if targets is not None:
+                raise ValueError('a packed step derives its targets from '
+                                 'tokens and segment_ids; pass none')
+            targets, weights = packed_lm_targets(tokens, segment_ids)
+        loss, grads = jax.value_and_grad(loss_fn)(
+            params, tokens, targets, config, mesh, positions=positions,
+            segment_ids=segment_ids, weights=weights)
         updates, opt_state = optimizer.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
         return params, opt_state, loss
@@ -718,10 +759,12 @@ def make_train_step(config: TransformerConfig, mesh=None, optimizer=None):
                                      is_leaf=lambda x: isinstance(
                                          x, type(bspec)))
     b_shard = NamedSharding(mesh, bspec)
-    jitted = jax.jit(step,
-                     in_shardings=(p_shard, None, b_shard, b_shard),
-                     out_shardings=(p_shard, None, None))
-    return optimizer, jitted
+    out_shardings = (p_shard, None, None)
+    unpacked = jax.jit(step, in_shardings=(p_shard, None, b_shard, b_shard),
+                       out_shardings=out_shardings)
+    packed = jax.jit(step, in_shardings=(p_shard, None) + (b_shard,) * 4,
+                     out_shardings=out_shardings)
+    return optimizer, _MeshStep(unpacked, packed)
 
 
 def make_forward(config: TransformerConfig):

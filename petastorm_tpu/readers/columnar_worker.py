@@ -217,9 +217,15 @@ def _decode_cells(cells, decode, n: int, fixed: bool,
 
 def _list_column_to_numpy(column: pa.ChunkedArray, field) -> np.ndarray:
     """List column -> numpy. Fixed-shape numeric lists take the zero-Python
-    path: flatten the arrow values buffer in C++ and reshape."""
+    path: flatten the arrow values buffer in C++ and reshape. Null-free 1-D
+    wildcard numeric lists flatten the same way and come out as an object
+    array of per-row views of the field's dtype into that one buffer."""
     shape = tuple(field.shape) if field.shape else ()
     fixed = shape and all(s is not None for s in shape)
+    if (shape == (None,) and column.null_count == 0
+            and field.numpy_dtype is not None
+            and np.dtype(field.numpy_dtype).kind in 'biuf'):
+        return _ragged_list_views(column, np.dtype(field.numpy_dtype))
     if fixed and column.null_count == 0:
         arr = column.combine_chunks()
         flat = arr.flatten().to_numpy(zero_copy_only=False)
@@ -238,6 +244,21 @@ def _list_column_to_numpy(column: pa.ChunkedArray, field) -> np.ndarray:
     out = np.empty(len(rows), dtype=object)
     for i, r in enumerate(rows):
         out[i] = np.asarray(r)
+    return out
+
+
+def _ragged_list_views(column: pa.ChunkedArray, dtype) -> np.ndarray:
+    """Object array of each row's values as a view into one flat ``dtype``
+    array (a 1-D wildcard list column without nulls)."""
+    arr = column.combine_chunks()
+    flat = arr.flatten().to_numpy(zero_copy_only=False)
+    if flat.dtype != dtype:
+        flat = flat.astype(dtype)
+    bounds = arr.offsets.to_numpy()
+    bounds = bounds - bounds[0]
+    out = np.empty(len(arr), dtype=object)
+    for i in range(len(arr)):
+        out[i] = flat[bounds[i]:bounds[i + 1]]
     return out
 
 
@@ -555,12 +576,16 @@ class ColumnarWorker(ParquetPieceWorker):
     def _apply_transform(self, columns: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
         """TransformSpec over a dict of column arrays (the columnar-path
         contract; the row path hands ``func`` one row dict at a time, the arrow
-        batch path a pandas frame)."""
+        batch path a pandas frame). Timed as its own stage,
+        ``worker_transform_s`` (a part of ``worker_decode_s``); a spec that
+        ``reports_counts`` adds to this worker's counters."""
         from petastorm_tpu.transform import apply_columnar_transform
         start = time.perf_counter()
         out = apply_columnar_transform(self._transform_spec,
-                                       self._transformed_schema, columns)
+                                       self._transformed_schema, columns,
+                                       self.record_count)
         elapsed = time.perf_counter() - start
+        self.record_time('worker_transform_s', elapsed)
         self.record_latency('decode', elapsed)
         self.record_span('transform', 'decode', start, elapsed)
         return out

@@ -37,15 +37,20 @@ class TransformSpec:
         device spec still runs on the host over the same columnar dict
         (jnp ops accept numpy arrays), so results do not depend on
         eligibility.
+    :param reports_counts: ``func`` takes a second argument,
+        ``record_count(name, n)``, through which it adds ``n`` to one of the
+        reader's ``ReaderStats`` counters (columnar path only; e.g.
+        ``packing.pack_transform``'s packing counters).
     """
 
     def __init__(self, func: Optional[Callable] = None,
                  edit_fields: Optional[List] = None,
                  removed_fields: Optional[List[str]] = None,
                  selected_fields: Optional[List[str]] = None,
-                 device: bool = False):
+                 device: bool = False, reports_counts: bool = False):
         self.func = func
         self.device = bool(device)
+        self.reports_counts = bool(reports_counts)
         self.edit_fields = [self._as_field(f) for f in (edit_fields or [])]
         self.removed_fields = list(removed_fields or [])
         self.selected_fields = list(selected_fields) if selected_fields is not None else None
@@ -80,11 +85,16 @@ def transform_schema(schema: Unischema, transform_spec: TransformSpec) -> Unisch
 
 
 def apply_columnar_transform(transform_spec: TransformSpec,
-                             transformed_schema: Unischema, columns):
+                             transformed_schema: Unischema, columns,
+                             record_count: Optional[Callable] = None):
     """The columnar transform contract, shared by the streaming columnar
-    worker and the indexed loader: ``func`` receives a dict of column arrays;
-    the result is filtered to the transformed schema's fields."""
+    worker and the indexed loader: ``func`` receives a dict of column arrays
+    (and, for a spec that ``reports_counts``, ``record_count``, ``None``
+    where no counters are kept); the result is filtered to the transformed
+    schema's fields."""
     if transform_spec.func is not None:
-        columns = transform_spec.func(columns)
+        columns = (transform_spec.func(columns, record_count)
+                   if transform_spec.reports_counts
+                   else transform_spec.func(columns))
     return {name: columns[name] for name in transformed_schema.fields
             if name in columns}

@@ -35,6 +35,8 @@ TIME_STAGES = (
     'worker_decode_s',   # codec decode / transform inside the worker:
                          # process() wall less io and transport, so with
                          # thread workers it counts GIL waits as decode
+    'worker_transform_s',  # the row-group TransformSpec on the columnar
+                           # worker (a part of worker_decode_s)
     'worker_publish_wait_s',  # worker blocked on a full results queue
     'serialize_s',       # payload -> transport frames (process pools)
     'deserialize_s',     # transport frames -> payload (consumer side)
@@ -107,6 +109,10 @@ COUNTERS = (
                           # after a membership change
     'rows_resumed',      # rows a takeover host resumed from a dead host's
                          # checkpointed lease cursor (never re-delivered)
+    'pack_rows',         # packed rows made by packing.pack_transform
+    'pack_tokens',       # document tokens placed in them
+    'pack_pad_tokens',   # padding slots left in them
+    'pack_docs_split',   # documents longer than a row, split into pieces
 )
 
 #: Occupancy gauges; each also keeps a ``<name>_max`` high-water mark.

@@ -190,7 +190,8 @@ class TestTransformerLm:
             batch = pack_documents(
                 [list(rng.integers(0, 256, n)) for n in (7, 5, 9, 11)],
                 seq_len=32, num_rows=2)
-            toks = jnp.asarray(batch.tokens, jnp.int32)
+            # the packer returns host arrays; put them on the device here
+            toks = jnp.asarray(batch.tokens)
             seg = jnp.asarray(batch.segment_ids)
             tgts, weights = packed_lm_targets(toks, seg)
             extra = dict(positions=jnp.asarray(batch.positions),

@@ -24,6 +24,8 @@ class TestPackDocuments:
         # greedy first-fit: [1,2,3|4,5|10] and [6,7,8,9|pad]
         assert out.tokens.shape == out.segment_ids.shape == out.positions.shape
         assert out.tokens.shape[1] == 6
+        # host arrays: placing them on a device is the loader's job
+        assert all(isinstance(a, np.ndarray) for a in out)
         for row_tok, row_seg, row_pos in zip(out.tokens, out.segment_ids,
                                              out.positions):
             # positions restart at 0 on every segment boundary
@@ -41,8 +43,7 @@ class TestPackDocuments:
                 for _ in range(37)]
         out = pack_documents(docs, seq_len=16)
         recovered = []
-        for row_tok, row_seg in zip(np.asarray(out.tokens),
-                                    np.asarray(out.segment_ids)):
+        for row_tok, row_seg in zip(out.tokens, out.segment_ids):
             for seg in range(1, int(row_seg.max()) + 1):
                 sel = row_seg == seg
                 if sel.any():
@@ -51,8 +52,8 @@ class TestPackDocuments:
 
     def test_padding_is_segment_zero(self):
         out = pack_documents([[1, 2]], seq_len=8, pad_token=0)
-        seg = np.asarray(out.segment_ids)[0]
-        tok = np.asarray(out.tokens)[0]
+        seg = out.segment_ids[0]
+        tok = out.tokens[0]
         assert (seg[:2] == 1).all() and (seg[2:] == 0).all()
         assert (tok[2:] == 0).all()
 
@@ -64,16 +65,15 @@ class TestPackDocuments:
         docs = [[i] * (i % 5 + 1) for i in range(20)]
         a = pack_documents(docs, seq_len=12)
         b = pack_documents(docs, seq_len=12)
-        np.testing.assert_array_equal(np.asarray(a.tokens),
-                                      np.asarray(b.tokens))
+        np.testing.assert_array_equal(a.tokens, b.tokens)
 
     def test_num_rows_pins_batch_dim(self):
         """Jitted consumers need a static batch dim: num_rows pads with
         all-padding rows and rejects overflow."""
         out = pack_documents([[1, 2], [3]], seq_len=4, num_rows=4)
         assert out.tokens.shape == (4, 4)
-        assert (np.asarray(out.segment_ids)[1:] == 0).all() or \
-               (np.asarray(out.segment_ids)[-2:] == 0).all()
+        assert (out.segment_ids[1:] == 0).all() or \
+               (out.segment_ids[-2:] == 0).all()
         with pytest.raises(ValueError, match='num_rows'):
             pack_documents([[1] * 4, [2] * 4, [3] * 4], seq_len=4, num_rows=2)
 
@@ -96,8 +96,8 @@ class TestPackedModelForward:
                              positions=packed.positions,
                              segment_ids=packed.segment_ids)
 
-        row_tok = np.asarray(packed.tokens)[0]
-        row_seg = np.asarray(packed.segment_ids)[0]
+        row_tok = packed.tokens[0]
+        row_seg = packed.segment_ids[0]
         for seg_id in range(1, int(row_seg.max()) + 1):
             sel = row_seg == seg_id
             doc = jnp.asarray(row_tok[sel])[None, :]
