@@ -18,7 +18,8 @@ PartitionSpecs, let XLA insert the collectives.
   the expert axis.
 
 Compute dtype is bfloat16 (MXU-native); params and softmax/statistics stay
-float32.
+float32. The flash kernels take the same line: bf16 MXU operands (``p`` and
+``ds`` cast to bf16), float32 accumulation and softmax statistics.
 """
 
 from __future__ import annotations

@@ -253,6 +253,21 @@ def blockwise_attention(q, k, v, *, causal: bool = True, block_k: int = 512,
 # Pallas TPU kernel
 # ---------------------------------------------------------------------------
 
+def _mxu_dot(a, b, dimension_numbers):
+    """One MXU product of the kernels: the operands in their common dtype
+    (the inputs' own, so bf16 tiles stay bf16), accumulated in float32.
+
+    Operands narrower than float32 multiply exactly at any precision, so they
+    take the default one: Mosaic refuses bf16 operands at the float32
+    precision a global ``jax_default_matmul_precision`` would ask for.
+    float32 operands follow that setting, as any matmul does."""
+    dtype = jnp.promote_types(a.dtype, b.dtype)
+    precision = None if dtype == jnp.float32 else jax.lax.Precision.DEFAULT
+    return jax.lax.dot_general(a.astype(dtype), b.astype(dtype),
+                               dimension_numbers, precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_q: int,
                   block_k: int, causal: bool, scale: float, kv_seq_len: int,
                   num_kv_blocks: int, with_lse: bool, segmented: bool = False,
@@ -300,11 +315,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_q: int,
 
     @pl.when(needed)
     def _compute():
-        q = q_ref[...].astype(jnp.float32)
-        k = k_ref[...].astype(jnp.float32)
-        v = v_ref[...].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mxu_dot(q_ref[...], k_ref[...], (((1,), (1,)), ((), ()))) * scale
         k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_k), 1)
         mask = k_pos < kv_seq_len                      # tail-padding mask
@@ -326,8 +337,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_q: int,
         p = jnp.where(mask, p, 0.0)
         corr = jnp.exp(m_prev - m_new)                 # (bq, 1)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        v = v_ref[...]
+        acc_ref[...] = acc_ref[...] * corr + _mxu_dot(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -614,16 +626,14 @@ def _bwd_recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
     kv tail padding, causality, and — when segment refs are given — packed
     cross-segment pairs; lse == _NEG_INF marks a fully-masked row
     — forward convention — and exp would overflow there, so it is gated out
-    explicitly), then ds = p·(do·vᵀ − Δ)·scale. Returns float32 operand
-    views plus (p, ds)."""
-    q = q_ref[...].astype(jnp.float32)
-    k = k_ref[...].astype(jnp.float32)
-    v = v_ref[...].astype(jnp.float32)
-    do = do_ref[...].astype(jnp.float32)
+    explicitly), then ds = p·(do·vᵀ − Δ)·scale. Returns the q, k and do
+    tiles as loaded (the MXU operands) plus float32 (p, ds)."""
+    q = q_ref[...]
+    k = k_ref[...]
+    do = do_ref[...]
     lse = lse_ref[...]                              # (bq, 1) float32
     delta = delta_ref[...]                          # (bq, 1) float32
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
+    s = _mxu_dot(q, k, (((1,), (1,)), ((), ()))) * scale
     k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (1, block_k), 1)
     mask = k_pos < kv_seq_len
@@ -638,8 +648,7 @@ def _bwd_recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
     mask = jnp.broadcast_to(mask, s.shape)
     live = mask & jnp.broadcast_to(lse > _NEG_INF / 2, s.shape)
     p = jnp.where(live, jnp.exp(s - lse), 0.0)      # (bq, bk)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
+    dp = _mxu_dot(do, v_ref[...], (((1,), (1,)), ((), ())))
     ds = p * (dp - delta) * scale                   # (bq, bk)
     return q, k, do, p, ds
 
@@ -683,9 +692,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kv_idx=kv_idx, block_q=block_q, block_k=block_k, causal=causal,
             scale=scale, kv_seq_len=kv_seq_len, segq_ref=segq_ref,
             segkv_ref=segkv_ref, window=window)
-        dq_acc[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dq_acc[...] += _mxu_dot(ds.astype(k.dtype), k,
+                                (((1,), (0,)), ((), ())))
 
     @pl.when(kv_idx == num_kv_blocks - 1)
     def _final():
@@ -730,12 +738,10 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             kv_idx=kv_idx, block_q=block_q, block_k=block_k, causal=causal,
             scale=scale, kv_seq_len=kv_seq_len, segq_ref=segq_ref,
             segkv_ref=segkv_ref, window=window)
-        dv_acc[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, D)
-        dk_acc[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # (bk, D)
+        dv_acc[...] += _mxu_dot(p.astype(do.dtype), do,
+                                (((0,), (0,)), ((), ())))   # (bk, D)
+        dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q,
+                                (((0,), (0,)), ((), ())))   # (bk, D)
 
     @pl.when(q_idx == num_q_blocks - 1)
     def _final():

@@ -15,7 +15,9 @@ from conftest import ref_attention as _exact
 
 from petastorm_tpu.ops.attention import blockwise_attention, flash_attention
 
-pytestmark = pytest.mark.slow    # kernels / model training: minutes-scale (fast lane skips)
+# kernels / model training are minutes-scale: the fast lane skips the classes
+# that run them, and keeps the jaxpr checks, which only trace
+slow = pytest.mark.slow
 
 _RNG = np.random.default_rng(0)
 
@@ -35,6 +37,71 @@ def _mk(b, h, lq, lk, d, dtype=jnp.float32):
     return q, k, v
 
 
+def _kernel_dots(jaxpr):
+    """The ``dot_general`` equations inside each ``pallas_call`` of
+    ``jaxpr``, one list per kernel, in trace order."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    def subjaxprs(eqn):
+        for value in eqn.params.values():
+            for x in value if isinstance(value, (tuple, list)) else (value,):
+                if isinstance(x, ClosedJaxpr):
+                    yield x.jaxpr
+                elif isinstance(x, Jaxpr):
+                    yield x
+
+    def dots(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == 'dot_general':
+                yield eqn
+            for sub in subjaxprs(eqn):
+                yield from dots(sub)
+
+    def kernels(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == 'pallas_call':
+                yield list(dots(eqn.params['jaxpr']))
+            else:
+                for sub in subjaxprs(eqn):
+                    yield from kernels(sub)
+
+    return list(kernels(jaxpr))
+
+
+@pytest.mark.parametrize('segmented', [False, True])
+@pytest.mark.parametrize('dtype', [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize('grad', [False, True])
+def test_kernel_mxu_operands_follow_input_dtype(dtype, segmented, grad):
+    """Every MXU product inside the forward, dq and dk/dv kernels takes
+    operands in the inputs' dtype (bf16 tiles stay bf16, p and ds are cast to
+    it) and accumulates in float32. Under a global 'highest' matmul precision
+    float32 products keep it and bf16 ones take the default."""
+    q = jnp.zeros((2, 2, 256, 64), dtype)
+    seg = jnp.zeros((2, 256), jnp.int32) if segmented else None
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, block_q=128,
+                               block_k=128, backend='pallas',
+                               segment_ids=seg)
+
+    if grad:
+        fn = jax.grad(lambda q, k, v: jnp.sum(
+            attend(q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
+    else:
+        fn = attend
+    with jax.default_matmul_precision('highest'):
+        kernels = _kernel_dots(jax.make_jaxpr(fn)(q, q, q).jaxpr)
+    # forward: q·kᵀ, p·v; dq: q·kᵀ, do·vᵀ, ds·k; dk/dv: those and p·do, ds·q
+    assert [len(d) for d in kernels] == ([2, 3, 4] if grad else [2])
+    precision = (jax.lax.Precision.HIGHEST if dtype == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    for eqn in (e for d in kernels for e in d):
+        assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype], eqn
+        assert eqn.params['preferred_element_type'] == jnp.float32, eqn
+        assert eqn.params['precision'] == (precision, precision), eqn
+
+
+@slow
 class TestFlashInterpret:
     @pytest.mark.parametrize('lq,lk,causal', [
         (256, 256, True), (256, 256, False),
@@ -153,6 +220,35 @@ class TestFlashInterpret:
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(ref), atol=3e-2)
 
+    @pytest.mark.parametrize('case', ['plain', 'segments', 'gqa'])
+    def test_bf16_grads_match_float32_oracle(self, cpu, case):
+        """bf16 inputs (bf16 MXU operands, float32 accumulation) against
+        the float32 jnp path on the same values: output and gradients within
+        1e-2 of the oracle's largest value. These cases read 1.8e-3 to
+        5.3e-3; with float32 operands in the kernels, 1.8e-3 to 3.7e-3."""
+        rng = np.random.default_rng(11)
+        hkv = 2 if case == 'gqa' else 4
+        q, k, v, do = (jnp.asarray(rng.standard_normal((1, h, 512, 64)),
+                                   jnp.bfloat16)
+                       for h in (4, hkv, hkv, 4))
+        seg = None
+        if case == 'segments':
+            seg = jnp.asarray(np.repeat([0, 1, 2], [200, 180, 132])[None])
+
+        def run(backend, *args):
+            o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, block_q=128, block_k=128,
+                backend=backend, segment_ids=seg), *args)
+            return (o,) + vjp(do.astype(o.dtype))
+
+        got = run('interpret', q, k, v)
+        want = run('jnp', *(x.astype(jnp.float32) for x in (q, k, v)))
+        for a, b in zip(got, want):
+            assert a.dtype == jnp.bfloat16
+            b = np.asarray(b)
+            err = np.max(np.abs(np.asarray(a, np.float32) - b))
+            assert err <= 1e-2 * np.max(np.abs(b)), err
+
     def test_jnp_backend_is_blockwise(self, cpu):
         q, k, v = _mk(1, 1, 64, 64, 16)
         a = flash_attention(q, k, v, causal=True, backend='jnp')
@@ -160,6 +256,7 @@ class TestFlashInterpret:
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
 
 
+@slow
 @pytest.mark.skipif(jax.default_backend() != 'tpu',
                     reason='needs real TPU hardware')
 class TestFlashTPU:

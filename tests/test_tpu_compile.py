@@ -76,6 +76,29 @@ def test_flash_fused_backward(topo):
     assert hlo.count('tpu_custom_call') >= 3
 
 
+@pytest.mark.parametrize('precision', ['default', 'highest'])
+def test_flash_segmented_backward_at_lm_widths(topo, precision):
+    """The packed LM's attention, forward and fused backward, at its
+    per-chip widths: bf16 (4, 12, 2048, 64) with segment ids, the kernels'
+    MXU operands bf16, also under a global 'highest' matmul precision
+    (which Mosaic refuses for bf16 operands if the kernels pass it on)."""
+    from petastorm_tpu.ops.attention import flash_attention
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    qkv = [jax.ShapeDtypeStruct((4, 12, 2048, 64), jnp.bfloat16,
+                                sharding=chip) for _ in range(3)]
+    seg = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=chip)
+
+    def loss(q, k, v, seg):
+        o = flash_attention(q, k, v, causal=True, backend='pallas',
+                            bwd='pallas', segment_ids=seg)
+        return jnp.sum(o.astype(jnp.float32))
+
+    with jax.default_matmul_precision(precision):
+        hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), *qkv, seg)
+    assert hlo.count('tpu_custom_call') >= 3
+
+
 def test_flash_sharded_over_data_mesh(topo):
     """The LM's flash call on a data-parallel mesh: GSPMD refuses a bare
     Mosaic kernel, so it must sit inside shard_map over 'data'."""
