@@ -107,7 +107,7 @@ def _value_and_grad(config, mesh=None):
 
 
 def _token_store(workdir, seed, rows, seq_len, vocab):
-    from petastorm_tpu.benchmark.northstar import generate_token_dataset
+    from petastorm_tpu.test_util.dataset_gen import generate_token_dataset
 
     url = 'file://' + os.path.join(workdir, 'tokens')
     start = time.perf_counter()
@@ -224,9 +224,9 @@ def image_phase(workdir, *, seed=0, rows=512, batch_size=64, steps=3,
     import jax
 
     from petastorm_tpu import make_reader
-    from petastorm_tpu.benchmark.northstar import generate_imagenet_dataset
     from petastorm_tpu.jax_utils import JaxDataLoader
     from petastorm_tpu.models import image_cnn
+    from petastorm_tpu.test_util.dataset_gen import generate_imagenet_dataset
 
     url = 'file://' + os.path.join(workdir, 'imagenet')
     start = time.perf_counter()

@@ -362,7 +362,7 @@ def analyze_paths(paths: Sequence[str], root: str,
 #: runtime code. Tests exercise the rules through fixtures
 #: (``tests/test_petalint.py``) and carry their own idioms (anonymous probe
 #: threads, deliberate wedges), so they are opt-in via explicit paths.
-DEFAULT_PATHS = ('petastorm_tpu', 'ci', 'bench.py')
+DEFAULT_PATHS = ('petastorm_tpu', 'ci')
 
 DEFAULT_BASELINE = os.path.join('ci', 'analysis', 'baseline.json')
 

@@ -63,11 +63,12 @@ class AtomicPublishRule(Rule):
     written with a bare ``open(path, 'w')`` — a crash mid-dump (exactly when
     the artifact matters) left truncated JSON that tooling rejected, or
     clobbered the previous good artifact. The fix became
-    ``utils.atomic_write`` (tmp + ``os.replace``); PR 7 re-found the same
-    shape in ``bench.py --out``. This rule makes the *next* bare artifact
-    write a CI failure: any ``open()`` in write/create mode must live in a
-    function that also publishes via ``os.replace``/``os.rename``/
-    ``os.link`` (tmp-file pattern) or calls ``atomic_write``.
+    ``utils.atomic_write`` (tmp + ``os.replace``), and the same shape turned
+    up again in a benchmark's ``--out`` writer. This rule makes the *next*
+    bare artifact write a CI failure: any ``open()`` in write/create mode
+    must live in a function that also publishes via ``os.replace``/
+    ``os.rename``/``os.link`` (tmp-file pattern) or calls
+    ``atomic_write``.
 
     Carve-outs: append mode (``'a'`` is a line-append protocol, e.g. the
     JSONL metrics emitter), and a ``with open(path, 'w'): pass`` touch (a
@@ -79,7 +80,7 @@ class AtomicPublishRule(Rule):
                    'tmp + os.replace/os.link')
     interests = (ast.Call,)
 
-    SCOPE = ('petastorm_tpu/*', 'ci/*', 'bench.py')
+    SCOPE = ('petastorm_tpu/*', 'ci/*')
     _PUBLISHERS = ('os.replace', 'os.rename', 'os.link', 'atomic_write',
                    'utils.atomic_write')
 
@@ -358,7 +359,7 @@ class ThreadLifecycleRule(Rule):
                    'self-held threads need a joining method')
     interests = (ast.Call,)
 
-    SCOPE = ('petastorm_tpu/*', 'ci/*', 'bench.py')
+    SCOPE = ('petastorm_tpu/*', 'ci/*')
     _PREFIX = 'petastorm-tpu-'
 
     def applies_to(self, relpath: str) -> bool:

@@ -38,26 +38,14 @@ case "$LANE" in
     ;;
 esac
 
-echo '== readahead quick bench (serial vs prefetched row-group reads) =='
-python -m petastorm_tpu.benchmark.readahead --quick
-
-echo '== trace-overhead quick bench (span tracer on vs off) =='
-python -m petastorm_tpu.benchmark.trace_overhead --quick
-
 echo '== petalint self-tests (rule fixtures, baseline workflow, lockdep unit) =='
 python -m pytest tests/test_petalint.py -q
 
 echo '== health quick checks (watchdog + debug endpoint + wedge fixtures; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_health.py -q
 
-echo '== health-overhead quick bench (heartbeats+watchdog+endpoint on vs off) =='
-python -m petastorm_tpu.benchmark.health_overhead --quick
-
 echo '== lineage quick checks (provenance, coverage audit, quarantine, replay) =='
 python -m pytest tests/test_lineage.py -q
-
-echo '== lineage-overhead quick bench (provenance+audit ledgers on vs off) =='
-python -m petastorm_tpu.benchmark.lineage_overhead --quick
 
 echo '== resilience quick checks (retry policy, hedging, fault injector) =='
 python -m pytest tests/test_resilience.py -q
@@ -65,20 +53,11 @@ python -m pytest tests/test_resilience.py -q
 echo '== chaos matrix (seeded fault scenarios x both pool types, audit-complete; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_chaos.py -q
 
-echo '== chaos quick bench (hedged vs unhedged reads under injected tail latency) =='
-python -m petastorm_tpu.benchmark.chaos --quick
-
 echo '== latency quick checks (histograms, rolling windows, SLO monitor, /slo; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_latency.py -q
 
-echo '== latency-overhead quick bench (streaming histograms + SLO monitor on vs off) =='
-python -m petastorm_tpu.benchmark.latency_overhead --quick
-
 echo '== autotune quick checks (controller policy, live pool resize, revert, kill switch; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_autotune.py -q
-
-echo '== autotune quick bench (mis-tuned recovery + steady guard on the slow-io mnist line) =='
-python -m petastorm_tpu.benchmark.autotune --quick
 
 echo '== shared-cache quick checks (tiered segments, pins, concurrent attach; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_sharedcache.py -q
@@ -86,35 +65,17 @@ PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_sharedcache.py -q
 echo '== worker-pool checks under the lockdep-lite harness (lock-order graph) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_workers_pool.py -q
 
-echo '== shared-cache quick bench (K readers x one dataset, decoded once) =='
-python -m petastorm_tpu.benchmark.shared_cache --quick
-
 echo '== object-store quick checks (range planning, ranged reads, trace replay, peer cache; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_objectstore.py -q
-
-echo '== object-store quick bench (serial/prebuffer/ranged under the recorded trace + pod dedup) =='
-python -m petastorm_tpu.benchmark.object_store --quick
 
 echo '== pod-observability quick checks (snapshot/merge/certificate, trace headers, kill switch; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 python -m pytest tests/test_podobs.py -q
 
-echo '== pod-observability quick bench (overhead A/B under the recorded trace + K-host merged certificate) =='
-JAX_PLATFORMS=cpu python -m petastorm_tpu.benchmark.podobs --quick
-
 echo '== pod-elasticity quick checks (membership/lease/ledger, host-death/join chaos, exactly-once certificate; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 JAX_PLATFORMS=cpu python -m pytest tests/test_podelastic.py -q
 
-echo '== pod-elasticity quick bench (clean-path overhead under the recorded trace + host-death recovery vs restart) =='
-JAX_PLATFORMS=cpu python -m petastorm_tpu.benchmark.podelastic --quick
-
 echo '== profiler quick checks (attribution, calibration cache, advisor, /profile) =='
 python -m pytest tests/test_profiler.py -q
-
-echo '== roofline quick bench (calibrated ceilings + attribution on the mnist decode line) =='
-python -m petastorm_tpu.benchmark.roofline --quick
-
-echo '== batched-decode quick bench (vectorized vs per-cell codec decode, bit-identity) =='
-python -m petastorm_tpu.benchmark.decode_batch --quick
 
 echo '== batched-decode quick checks (bit-identity property tests, quarantine, lineage audit) =='
 python -m pytest tests/test_decode_batch.py -q
@@ -122,20 +83,8 @@ python -m pytest tests/test_decode_batch.py -q
 echo '== device-decode quick checks (bytes-through plan/decline matrix, jit bit-identity, coverage audit) =='
 JAX_PLATFORMS=cpu python -m pytest tests/test_device_decode.py -q
 
-echo '== device-decode quick bench (kill-switch A/B, raw-shipping counters, probe ceilings) =='
-JAX_PLATFORMS=cpu python -m petastorm_tpu.benchmark.device_decode --quick
-
 echo '== goodput quick checks (step decomposition, explain_step, pod merge, kill switch; lockdep on) =='
 PETASTORM_TPU_LOCKDEP=1 JAX_PLATFORMS=cpu python -m pytest tests/test_goodput.py -q
-
-echo '== goodput quick bench (overhead A/B, slow-data vs slow-compute classification, pod straggler) =='
-JAX_PLATFORMS=cpu python -m petastorm_tpu.benchmark.goodput --quick
-
-echo '== bench-docs consistency gate =='
-python ci/check_bench_docs.py
-
-echo '== perf-trajectory regression gate (committed BENCH_*.json) =='
-python ci/check_perf_regression.py
 
 echo '== multi-chip dry run (8 virtual devices) =='
 XLA_FLAGS=--xla_force_host_platform_device_count=8 \

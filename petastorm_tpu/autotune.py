@@ -16,7 +16,7 @@ runs a **sense → predict → actuate** loop against a live reader.
   over the **neighbor set** of the current configuration — workers ±1,
   readahead depth ±1 — using the *measured* per-worker efficiency factor
   (:func:`petastorm_tpu.profiler.measured_worker_efficiency`) so the model
-  can predict negative scaling (the BENCH_r13 GIL convoy). The best
+  can predict negative scaling (a GIL convoy). The best
   predicted move is taken only when its expected gain clears the hysteresis
   threshold, and never when the (crude, documented) latency model predicts
   it breaches the reader's ``p99_e2e_ms`` SLO target. Ventilation window

@@ -29,8 +29,7 @@ import pyarrow as pa
 #: through the per-cell loop — the uniform observability/behavior kill
 #: switch shape (``PETASTORM_TPU_HEALTH``, ``PETASTORM_TPU_LINEAGE``,
 #: ``PETASTORM_TPU_PROFILER``). The two paths are bit-identical by
-#: contract (``docs/decode.md``); the switch exists for A/B measurement
-#: (``benchmark/decode_batch.py``) and as an escape hatch.
+#: contract (``docs/decode.md``); the switch is an escape hatch.
 BATCHED_DECODE_ENV_VAR = 'PETASTORM_TPU_BATCHED_DECODE'
 
 

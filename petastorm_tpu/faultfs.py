@@ -6,9 +6,7 @@ Chaos-testing a storage pipeline only proves something when the chaos is
 a bug report nobody can act on. Every injection decision here is a pure
 function of ``(seed, path, operation, occurrence-index)`` — run the same
 scenario with the same seed over the same access sequence and the exact same
-reads fail, straggle, truncate or kill. This generalizes the ad-hoc slow-IO
-shim ``benchmark/readahead.py`` grew for BENCH_r07 (which is now a
-fixed-latency scenario of this module) into the full fault model:
+reads fail, straggle, truncate or kill. The fault model:
 
 ========================  ====================================================
 scenario                  injected faults
@@ -20,7 +18,10 @@ scenario                  injected faults
                           property under test)
 ``tail-latency``          every read pays ``base_latency_s``; a ``tail_rate``
                           fraction pays ``tail_latency_s`` (heavy-tailed
-                          first-byte latency — the hedging benchmark's store)
+                          first-byte latency — the store hedged reads face)
+``fixed-latency``         every read pays ``seconds_per_read`` plus
+                          ``seconds_per_mb`` per MB read, faultlessly (slow
+                          storage)
 ``read-hangs``            a ``hang_rate`` fraction of reads sleep ``hang_s``
                           (the straggler/wedge shape hedges + watchdogs see)
 ``truncated-reads``       a ``truncate_rate`` fraction of reads return short
@@ -36,7 +37,8 @@ scenario                  injected faults
 ``trace-replay``          every read pays a first-byte latency + size/bandwidth
                           delay drawn from a *recorded* object-store trace
                           (``trace=<file-or-builtin-name>``; see
-                          ``benchmark/traces/`` and ``docs/object_store.md``) —
+                          ``petastorm_tpu/traces/`` and
+                          ``docs/object_store.md``) —
                           deterministic per (seed, path, range, occurrence), so
                           hedge thresholds and range planning are tuned against
                           a realistic S3-shaped tail without cloud credentials
@@ -77,7 +79,7 @@ logger = logging.getLogger(__name__)
 CHAOS_ENV_VAR = 'PETASTORM_TPU_CHAOS'
 
 #: Scenario registry: name -> default params. Every param can be overridden
-#: by constructing a :class:`FaultInjector` directly (benchmarks do).
+#: by constructing a :class:`FaultInjector` directly (tests do).
 SCENARIOS: Dict[str, dict] = {
     'none': {},
     'transient-errors': dict(error_rate=0.25, cooldown_reads=64),
@@ -87,13 +89,12 @@ SCENARIOS: Dict[str, dict] = {
     'truncated-reads': dict(truncate_rate=0.2, cooldown_reads=64),
     'worker-kill': dict(kill_after_reads=5, max_kills=1),
     'cache-enospc': dict(enospc_rate=1.0),
-    # the BENCH_r07 slow-IO shim as a scenario: every read pays a fixed
-    # latency (plus an optional per-byte bandwidth cost), faultlessly —
-    # what benchmark/readahead.py's SlowFilesystem now resolves to
+    # slow storage: every read pays a fixed latency (plus an optional
+    # per-byte bandwidth cost), faultlessly
     'fixed-latency': dict(seconds_per_read=0.0, seconds_per_mb=0.0),
     # replay a recorded object-store latency/bandwidth distribution:
     # trace = path to a trace JSON or a builtin name under
-    # benchmark/traces/ (e.g. 's3-us-east-1'); scales stretch/shrink the
+    # petastorm_tpu/traces/ (e.g. 's3-us-east-1'); scales stretch/shrink the
     # recorded samples without re-recording
     'trace-replay': dict(trace='', latency_scale=1.0, bandwidth_scale=1.0),
     # pod-elasticity scenarios (consulted by petastorm_tpu.podelastic, NOT
@@ -106,11 +107,11 @@ SCENARIOS: Dict[str, dict] = {
 
 def trace_path(name: str) -> str:
     """Resolve a trace spec to a file path: an existing path is itself; a
-    bare name resolves to the committed ``benchmark/traces/<name>.json``."""
+    bare name resolves to the committed ``traces/<name>.json``."""
     if os.path.exists(name):
         return name
     builtin = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           'benchmark', 'traces', name + '.json')
+                           'traces', name + '.json')
     if os.path.exists(builtin):
         return builtin
     raise ValueError('unknown trace {!r}: not a file, and no builtin trace '
@@ -274,7 +275,7 @@ class FaultInjector:
     def after_read(self, path: str, data):
         """Runs on every wrapped ``read()``'s returned bytes: may truncate
         (``truncated-reads``) or sleep (``fixed-latency`` — after the inner
-        read completes, matching the BENCH_r07 shim's accounting)."""
+        read completes)."""
         if self.scenario == 'fixed-latency':
             p = self.params
             nbytes = len(data) if data is not None else 0
@@ -392,8 +393,7 @@ class FaultInjector:
 
 class FaultyFile:
     """File wrapper routing every ``read()`` through the injector (and
-    counting reads/bytes on the owning filesystem, replacing the BENCH_r07
-    shim's accounting)."""
+    counting reads/bytes on the owning filesystem)."""
 
     def __init__(self, inner, owner: 'FaultyFilesystem', path: str):
         self._inner = inner

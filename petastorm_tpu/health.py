@@ -42,7 +42,7 @@ Three pieces:
   ``/diagnostics`` (stats + heartbeats + verdict as JSON) and ``/stacks``.
 
 Heartbeat publishing is on by default and costs a few attribute assignments
-per item (measured ~0 on the throughput bench, ``BENCH_r09.json``); set
+per item; set
 ``PETASTORM_TPU_HEALTH=0`` to compile it out of the workers entirely. The
 watchdog thread and HTTP server only exist when requested
 (``stall_timeout=`` / ``debug_port=``). See ``docs/health.md``.

@@ -33,9 +33,8 @@ contention. This module adds the distribution layer:
   ``error_budget``. ``burn_rate >= 1`` is a **hard breach** — the budget is
   spent — and can optionally flip ``/healthz`` to 503.
 
-Everything is **on by default** and measured within noise
-(``BENCH_r14.json``); set ``PETASTORM_TPU_LATENCY=0`` to create no histogram
-state at all. See ``docs/latency.md``.
+Everything is **on by default**; set ``PETASTORM_TPU_LATENCY=0`` to create
+no histogram state at all. See ``docs/latency.md``.
 """
 
 from __future__ import annotations

@@ -3,7 +3,8 @@
 Convs run in NHWC with bfloat16 compute (params float32), channel counts are
 multiples of 8/128 where it matters, and the whole step jits to a single XLA
 program — the image-side analogue of the transformer flagship. Used by
-``examples/imagenet`` and the image-decode north-star bench.
+``examples/imagenet`` and the benchmark's image driver
+(``chipbench/drivers/image_cnn.py``).
 """
 
 from __future__ import annotations

@@ -1,10 +1,9 @@
 """Device-side decode: jittable decoders for bytes-through columns.
 
-BENCH_r13 closed the host half of the decode wall and named what remains:
-per-cell codec cost dominates small payloads, and thread workers convoy on
-the GIL around sub-quantum decode calls. Both walls fall the same way —
-stop decoding on the host. This module is the device half of that plan
-(docs/decode.md "Device-side decode"):
+On the host, per-cell codec cost dominates small payloads, and thread
+workers convoy on the GIL around sub-quantum decode calls. Both walls fall
+the same way — stop decoding on the host. This module is the device half
+of that plan (docs/decode.md "Device-side decode"):
 
 - **Plan time** (:func:`plan_device_decode`): per column, decide at reader
   construction whether the raw stored payload can decode *on the

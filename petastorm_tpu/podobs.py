@@ -35,11 +35,10 @@ Three pieces:
   :func:`petastorm_tpu.tracing.stitch_pod_trace` can emit one aligned
   timeline across hosts.
 
-Everything is **on by default** and measured within noise
-(``BENCH_r19.json``); set ``PETASTORM_TPU_PODOBS=0`` to create no thread,
-no routes, and no files: the observe/podmetrics routes 404, the read-plane
-span/latency instrumentation compiles out to one boolean test, and no
-aggregator state exists anywhere. The observer itself never spawns a
+Everything is **on by default**; set ``PETASTORM_TPU_PODOBS=0`` to create
+no thread, no routes, and no files: the observe/podmetrics routes 404, the
+read-plane span/latency instrumentation compiles out to one boolean test,
+and no aggregator state exists anywhere. The observer itself never spawns a
 thread — it polls on demand (a call, a CLI run, or an HTTP request to the
 ``/podmetrics`` route of whichever host embeds it). See
 ``docs/pod_observability.md``.

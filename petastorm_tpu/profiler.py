@@ -3,9 +3,8 @@ attribution, and a what-if advisor.
 
 The sensors built in PRs 1-5 (``ReaderStats``, spans, heartbeats) answer
 *what the pipeline did*; none of them answer *what the host could have
-done* — VERDICT.md's standing complaint is that the decode-bound image
-lines have "no measured I/O ceiling to judge the cached line's samples/sec
-against". This module is the model layer on top of the sensors (the role
+done*, so a decode-bound line's samples/sec has no measured ceiling to be
+judged against. This module is the model layer on top of the sensors (the role
 tf.data's AUTOTUNE analysis layer plays over its raw counters):
 
 - **Calibration micro-probes** (:func:`calibrate`) measure this host's
@@ -31,9 +30,7 @@ tf.data's AUTOTUNE analysis layer plays over its raw counters):
 - **What-if advisor** (:func:`advise`): ranked knob recommendations
   (``workers_count``, ``io_readahead``, ``cache_type='shared'``,
   ``reader_pool_type``) with predicted samples/s deltas from the same
-  throughput model (:func:`predict_throughput`), validated for direction
-  against the committed BENCH artifacts
-  (:func:`replay_against_artifacts`).
+  throughput model (:func:`predict_throughput`).
 
 Surfaces: ``reader.profile()`` / ``reader.explain_throughput()``, the
 ``GET /profile`` route on the debug endpoint, a ``roofline`` section in
@@ -81,8 +78,8 @@ PROBE_SCHEMA_VERSION = 4
 
 #: Pipeline stages a ceiling is calibrated for, in pipeline order.
 #: ``device_decode`` (jitted bytes-through decode) and ``ingest`` (raw
-#: payload host→device transfer) are probe-only ceilings consumed by the
-#: device-decode benchmark gate; they never bind the host span attribution.
+#: payload host→device transfer) are probe-only ceilings reported in the
+#: calibration; they never bind the host span attribution.
 CEILING_STAGES = ('io', 'decode', 'serialize', 'device_stage')
 
 #: Span name -> attribution stage. Spans whose name is not listed keep their
@@ -503,9 +500,9 @@ def _probe_device_decode(plans, raw_columns, rows) -> Optional[dict]:
     """The jitted bytes-through decode ceiling (docs/decode.md): header-strip
     + bitcast + reshape under ``jax.jit`` over resident raw grids — compute
     only, no transfer (the :func:`_probe_ingest` twin measures that). The
-    pair answers BENCH_r13's open question quantitatively: once decode moves
-    off the host, which wall is next — device decode FLOPs or the PCIe/ICI
-    ingest link. ``None`` when no jax backend initializes."""
+    pair says which wall is next once decode moves off the host: device
+    decode FLOPs or the PCIe/ICI ingest link. ``None`` when no jax backend
+    initializes."""
     try:
         import jax
 
@@ -791,14 +788,13 @@ def predict_throughput(ceilings: dict, workers: int = 1,
     The model (docs/profiling.md "Attribution math"):
 
     - decode scales with effective parallel workers ``min(workers,
-      cpu_count)`` (per BENCH_scaling.json: workers beyond cores
-      time-slice, they do not add decode), damped by ``worker_efficiency``
+      cpu_count)`` (workers beyond cores time-slice, they do not add
+      decode), damped by ``worker_efficiency``
       — the *measured* marginal value of each extra worker. ``1.0`` is
       ideal scaling (the default and the old behavior); ``0.0`` means
-      extra workers add nothing; **negative** values model the GIL-convoy
-      regime BENCH_r13 measured (2 thread workers 2.6x *slower* than 1 on
-      ~10µs decode calls: sub-GIL-quantum work makes workers serialize on
-      the lock instead of the codecs). Effective parallelism is
+      extra workers add nothing; **negative** values model a GIL convoy
+      (on ~10µs decode calls, sub-GIL-quantum work makes thread workers
+      serialize on the lock instead of the codecs). Effective parallelism is
       ``1 + worker_efficiency * (eff_workers - 1)``, floored at 0.05 so a
       pathological factor predicts "much slower", never zero;
     - storage is a shared resource (no worker scaling);
@@ -860,10 +856,9 @@ def measured_worker_efficiency(measured_samples_per_s,
     second is unknowable until tried, which is exactly why the autotune
     controller pairs this model with revert-on-regression.
 
-    This is how BENCH_r13's GIL-convoy evidence (w2 at 25% of the decode
-    ceiling vs w1 at 66%) becomes representable: the implied ``e`` is
-    strongly negative, and the model then predicts the *removal* of a
-    worker as a gain (see :func:`replay_against_artifacts`)."""
+    A GIL convoy (two workers slower than one) implies a strongly negative
+    ``e``, and the model then predicts the *removal* of a worker as a
+    gain."""
     if workers is None or workers <= 1:
         return None
     if not decode_ceiling or not measured_samples_per_s:
@@ -916,8 +911,8 @@ def build_profile(snapshot: dict, calibration: Optional[dict] = None,
     cpu_count = calibration.get('cpu_count') or 1
     io_overlap = bool(io_readahead) \
         or snapshot.get('io_overlap_fraction', 0.0) > 0.5
-    # A warm cache legitimately skips the io+decode the ceilings measure
-    # (BENCH_r11: 13.4x the roofline): when the snapshot proves the reads
+    # A warm cache legitimately skips the io+decode the ceilings measure:
+    # when the snapshot proves the reads
     # were mostly cache hits, judge against the post-cache stages instead.
     hits = snapshot.get('shared_hits', 0)
     misses = snapshot.get('shared_misses', 0)
@@ -1001,8 +996,8 @@ def explain(profile: dict) -> str:
     measured = profile.get('measured_samples_per_s') or 0.0
     if not profile.get('calibrated'):
         return ('measured {:.1f} samples/s; no calibration for this '
-                'dataset yet — run reader.profile() (or benchmark/'
-                'roofline.py) to measure the per-stage ceilings'
+                'dataset yet — run reader.profile() to measure the '
+                'per-stage ceilings'
                 .format(measured))
     binding = profile.get('binding_stage')
     ceiling = profile.get('binding_ceiling_samples_per_s') or 0.0
@@ -1121,118 +1116,3 @@ def advise(profile: dict, max_workers: Optional[int] = None) -> List[dict]:
                  'the zero-copy transport ceiling binds before decode does')
     recommendations.sort(key=lambda r: -r['predicted_delta_pct'])
     return recommendations
-
-
-# ---------------------------------------------------------------------------
-# model validation against the committed BENCH artifacts
-# ---------------------------------------------------------------------------
-
-def replay_against_artifacts(root: Optional[str] = None) -> List[dict]:
-    """Directional validation of the advisor's model against committed BENCH
-    artifacts: each check replays the model on a measured configuration pair
-    and verifies the model predicts the direction the measurement showed.
-    Returns ``[{'check', 'artifact', 'ok', 'detail'}, ...]`` (artifacts
-    absent from ``root`` are skipped, not failed — the profiler must work
-    outside the repo checkout)."""
-    root = root or os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    checks = []
-
-    def load(name):
-        try:
-            with open(os.path.join(root, name)) as f:
-                return json.load(f)
-        except (OSError, ValueError):
-            return None
-
-    # BENCH_r07: readahead overlapped a ~1:1 io:decode pipeline for 1.81x.
-    # Model: min(io, dec) / harmonic(io, dec) = 2.0 at 1:1 — direction up,
-    # bounded by 2 (the model must predict a gain, and not a fantasy one).
-    r07 = load('BENCH_r07.json')
-    if r07 is not None:
-        parsed = r07.get('parsed') or r07
-        speedup = (parsed.get('speedup_items_per_s')
-                   if isinstance(parsed, dict) else None)
-        ceilings = {'io': 100.0, 'decode': 100.0}
-        serial = predict_throughput(ceilings, io_overlap=False, cpu_count=1)
-        overlapped = predict_throughput(ceilings, io_overlap=True,
-                                        cpu_count=1)
-        model_gain = overlapped / serial
-        ok = 1.0 < model_gain <= 2.0 and (speedup is None or speedup > 1.0)
-        checks.append({'check': 'readahead_overlap_direction',
-                       'artifact': 'BENCH_r07.json', 'ok': ok,
-                       'detail': 'model {:.2f}x vs measured {}x'.format(
-                           model_gain, speedup)})
-    # BENCH_scaling: flat samples/s curve on a 1-core host. Model with
-    # cpu_count=1 must predict zero gain from extra workers.
-    scaling = load('BENCH_scaling.json')
-    if scaling is not None:
-        cpus = scaling.get('host_cpu_count') or 1
-        ceilings = {'io': 1e6, 'decode': 100.0}
-        one = predict_throughput(ceilings, workers=1, cpu_count=cpus,
-                                 io_overlap=True)
-        eight = predict_throughput(ceilings, workers=8, cpu_count=cpus,
-                                   io_overlap=True)
-        ok = (eight <= one * max(1, cpus) + 1e-9) and \
-            (cpus != 1 or abs(eight - one) < 1e-9)
-        checks.append({'check': 'worker_scaling_bounded_by_cores',
-                       'artifact': 'BENCH_scaling.json', 'ok': ok,
-                       'detail': 'model predicts {:.1f} -> {:.1f} on a '
-                                 '{}-core host'.format(one, eight, cpus)})
-    # BENCH_r11: a warm shared-cache pass beat the serial io+decode
-    # roofline. Model: cached throughput must be >= the uncached ceiling.
-    r11 = load('BENCH_r11.json')
-    if r11 is not None:
-        roof = (r11.get('roofline') or {}).get('samples_per_sec')
-        warm = (r11.get('warm') or {}).get('samples_per_sec')
-        ceilings = {'io': 1000.0, 'decode': 500.0, 'device_stage': 50000.0}
-        uncached = predict_throughput(ceilings, io_overlap=True, cpu_count=1)
-        cached = predict_throughput(ceilings, io_overlap=True, cpu_count=1,
-                                    cached=True)
-        ok = cached >= uncached and (not roof or not warm or warm >= roof)
-        checks.append({'check': 'warm_cache_exceeds_io_decode_roofline',
-                       'artifact': 'BENCH_r11.json', 'ok': ok,
-                       'detail': 'model cached {:.0f} >= uncached {:.0f}; '
-                                 'measured warm {} vs roofline {}'.format(
-                                     cached, uncached, warm, roof)})
-    # BENCH_r13: 2 thread workers measured ~2.6x SLOWER than 1 on the
-    # small-png mnist line (GIL convoy on ~10µs decode calls). With the
-    # measured per-worker efficiency factor the model must predict the w2
-    # direction DOWN — the honest-measurement note the default ideal-scaling
-    # model could not represent (and the regression the autotune
-    # controller's revert path exists to undo when it walks into it blind).
-    r13 = load('BENCH_r13.json')
-    if r13 is not None:
-        lines = r13.get('lines') or {}
-        w1 = (lines.get('mnist_w1_batched') or {}).get('samples_per_sec')
-        w2_line = lines.get('mnist_w2_batched') or {}
-        w2 = w2_line.get('samples_per_sec')
-        decode_ceiling = ((w2_line.get('roofline') or {})
-                          .get('ceilings') or {}).get('decode')
-        if w1 and w2 and decode_ceiling:
-            efficiency = measured_worker_efficiency(w2, decode_ceiling, 2)
-            ceilings = {'io': 10.0 * decode_ceiling,
-                        'decode': decode_ceiling}
-            base = predict_throughput(ceilings, workers=1, cpu_count=2,
-                                      io_overlap=True)
-            measured_model = predict_throughput(
-                ceilings, workers=2, cpu_count=2, io_overlap=True,
-                worker_efficiency=efficiency)
-            ideal_model = predict_throughput(ceilings, workers=2,
-                                             cpu_count=2, io_overlap=True)
-            # the measured factor must flip the predicted direction to
-            # match the measurement (down), while the ideal factor still
-            # predicts up — proving the knob adds representational power
-            # rather than just re-deriving the ideal curve
-            ok = (w2 < w1 and measured_model < base
-                  and ideal_model > base and efficiency is not None
-                  and efficiency < 0)
-            checks.append({
-                'check': 'gil_convoy_negative_scaling_direction',
-                'artifact': 'BENCH_r13.json', 'ok': ok,
-                'detail': 'measured w1 {:.0f} -> w2 {:.0f}; implied '
-                          'efficiency {:.2f}; model w2 {:.0f} vs w1 {:.0f} '
-                          '(ideal-scaling model said {:.0f})'.format(
-                              w1, w2, efficiency or 0.0,
-                              measured_model or 0.0, base or 0.0,
-                              ideal_model or 0.0)})
-    return checks

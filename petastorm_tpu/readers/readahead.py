@@ -1,8 +1,7 @@
 """Worker-side row-group readahead: overlap storage I/O with decode.
 
-PR-1 telemetry (``BENCH_r06.json``) showed the remaining infeed stall lives
-inside the piece workers: each worker performs a blocking ``read_row_group``
-and only then decodes, so storage latency and decode CPU serialize. This
+Without it, each piece worker performs a blocking ``read_row_group`` and
+only then decodes, so storage latency and decode CPU serialize. This
 module pipelines them — a single background reader thread per worker issues
 the parquet reads for the next K ventilated pieces while the worker thread
 decodes the current one (the tf.data-style prefetch discipline petastorm's

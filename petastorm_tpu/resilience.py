@@ -17,7 +17,7 @@ substrate all three now share, plus the tail-latency weapon none of them had:
   live p95 of recent read latencies (the classic tail-at-scale move): first
   result wins, the loser is cancelled (its result discarded, its thread
   abandoned as a daemon). Hedging trades a small amount of extra load for a
-  large cut in p99 — measured in ``BENCH_r16.json``.
+  cut in p99.
 - :class:`ResilientIO` — the worker-facing bundle wiring both under
   ``piece_worker._read_row_group`` and the readahead thread, accumulating
   the ``io_retries`` / ``io_hedges`` / ``io_hedge_wins`` /
@@ -342,8 +342,8 @@ class HedgedRead:
         #: (winner AND abandoned loser) with ``{'tag', 'start_s', 'dur_s',
         #: 'won', 'cancelled_by_hedge', 'description'}`` — the loser of a
         #: decided race is the attempt hedging cancelled, which counters
-        #: alone cannot show (satellite: BENCH_r18's "0 hedges fired" claim
-        #: must be visible in a trace). May be called from a race thread;
+        #: alone cannot show (a run in which no hedge fired must be visible
+        #: as such in a trace). May be called from a race thread;
         #: the callback must be thread-safe.
         self._on_attempt = on_attempt
         # live race threads (winners AND abandoned losers): drained at

@@ -19,8 +19,7 @@ out-of-band :class:`pickle.PickleBuffer`\\ s) so ndarray/Arrow payload bytes
 are never copied into a pickle blob.
 
 Each instance counts ``copies`` (full-payload memcpys it performed) and
-``bytes_moved`` — the counters ``benchmark/transport.py`` and the acceptance
-assertions read.
+``bytes_moved`` — the counters the pool's stats and the tests read.
 """
 
 from __future__ import annotations

@@ -133,6 +133,42 @@ def ref_attention(q, k, v, causal=True):
     return jnp.einsum('...qk,...kd->...qd', jax.nn.softmax(s, axis=-1), v)
 
 
+def make_ngram_lm_parts(window, chunk, d_model, n_layers, n_heads, d_ff,
+                        vocab):
+    """The NGram LM loop shared by the streaming and indexed NGram test
+    modules: the window spec and a ``step_fn`` that concatenates a window's
+    timestep chunks on device into one (B, window·chunk) sequence and runs
+    the LM train step on the shift-by-one (inputs, targets)."""
+    import jax
+    import jax.numpy as jnp
+
+    from petastorm_tpu.models import transformer_lm as tlm
+    from petastorm_tpu.ngram import NGram
+
+    config = tlm.TransformerConfig(vocab_size=vocab, d_model=d_model,
+                                   n_heads=n_heads, n_layers=n_layers,
+                                   d_ff=d_ff, max_seq_len=window * chunk)
+    params = tlm.init(jax.random.PRNGKey(0), config)
+    optimizer, step = tlm.make_train_step(config)
+    state = {'params': params, 'opt': optimizer.init(params)}
+    fields = {0: ['ts', 'tokens']}
+    fields.update({i: ['tokens'] for i in range(1, window)})
+    ngram = NGram(fields, delta_threshold=1, timestamp_field='ts')
+
+    @jax.jit
+    def concat_and_step(params, opt_state, chunks):
+        seq = jnp.concatenate(chunks, axis=1)        # (B, window*chunk)
+        return step(params, opt_state, seq[:, :-1], seq[:, 1:])
+
+    def step_fn(batch):
+        chunks = [batch[i]['tokens'] for i in range(window)]
+        state['params'], state['opt'], loss = concat_and_step(
+            state['params'], state['opt'], chunks)
+        return loss
+
+    return ngram, step_fn
+
+
 @pytest.fixture(scope='session')
 def synthetic_dataset(tmp_path_factory):
     """Full-featured petastorm_tpu dataset (images, matrices, scalars,

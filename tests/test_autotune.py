@@ -154,7 +154,7 @@ def test_converges_io_bound_raises_readahead():
 
 def test_revert_on_regression_fires_and_quarantines():
     """Rigged model: predicted +100% from a second worker, measured -80%
-    (the BENCH_r13 GIL-convoy shape). The move must be undone and that
+    (the GIL-convoy shape). The move must be undone and that
     (knob, direction) locked out for quarantine_ticks."""
     clock = [0.0]
     state = {'items_out': 0, 'worker_decode_s': 5.0}
@@ -298,7 +298,7 @@ def test_options_validation_rejects_typos():
 
 
 def _readahead_url(tmp_path, rows=96, rows_per_group=8):
-    from petastorm_tpu.benchmark.readahead import generate_readahead_dataset
+    from petastorm_tpu.test_util.dataset_gen import generate_readahead_dataset
     url = 'file://' + str(tmp_path / 'ds')
     generate_readahead_dataset(url, rows=rows, rows_per_group=rows_per_group)
     return url

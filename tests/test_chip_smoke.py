@@ -102,18 +102,6 @@ def test_refuses_without_tpu(tmp_path):
     assert os.listdir(str(tmp_path)) == []     # no store was generated
 
 
-def test_bench_runs_off_the_tpu_only_when_asked(monkeypatch):
-    spec = importlib.util.spec_from_file_location(
-        'bench', os.path.join(ROOT, 'bench.py'))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    monkeypatch.setenv('JAX_PLATFORMS', 'cpu')
-    assert bench._platform() == 'cpu'
-    monkeypatch.delenv('JAX_PLATFORMS')
-    with pytest.raises(SystemExit, match='no TPU found'):
-        bench._platform()
-
-
 def test_device_decode_planning_raises_on_a_broken_backend(monkeypatch):
     from petastorm_tpu.ops.decode import jax_backend_available
 

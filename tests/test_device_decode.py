@@ -345,7 +345,7 @@ class TestFusedInfeed:
 
 @pytest.fixture(scope='module')
 def token_store(tmp_path_factory):
-    from petastorm_tpu.benchmark.northstar import generate_token_dataset
+    from petastorm_tpu.test_util.dataset_gen import generate_token_dataset
     url = 'file://' + str(tmp_path_factory.mktemp('device_decode') / 'tok')
     generate_token_dataset(url, rows=64, seq_len=16, vocab=64, seed=3,
                            row_group_size_mb=0.01, ndarray_codec=True)

@@ -475,7 +475,7 @@ class TestFlightRecord:
 
 @pytest.fixture(scope='module')
 def token_store(tmp_path_factory):
-    from petastorm_tpu.benchmark.northstar import generate_token_dataset
+    from petastorm_tpu.test_util.dataset_gen import generate_token_dataset
     url = 'file://' + str(tmp_path_factory.mktemp('goodput') / 'tok')
     generate_token_dataset(url, rows=48, seq_len=8, vocab=64, seed=5,
                            row_group_size_mb=0.01, ndarray_codec=True)

@@ -462,17 +462,30 @@ class TestShardedIndexedNGram:
 
 @pytest.mark.slow
 def test_indexed_ngram_bench_runs(tmp_path):
-    """The northstar indexed-NGram LM bench drives end to end."""
-    from petastorm_tpu.benchmark.northstar import (
-        generate_timeseries_token_dataset,
-        run_indexed_ngram_transformer_train_bench)
+    """The indexed NGram loader feeds three LM train steps end to end."""
+    from conftest import make_ngram_lm_parts
+    from petastorm_tpu.test_util.dataset_gen import \
+        generate_timeseries_token_dataset
+
     url = 'file://' + str(tmp_path / 'bench_tok')
     generate_timeseries_token_dataset(url, rows=96, chunk=16, vocab=256)
-    report = run_indexed_ngram_transformer_train_bench(
-        url, window=2, chunk=16, batch_size=4, num_steps=3, warmup_steps=1,
-        workers_count=2, d_model=32, n_layers=1, n_heads=2, d_ff=64,
-        vocab=256)
-    assert report.steps == 3 and report.samples == 12
+    ngram, step_fn = make_ngram_lm_parts(window=2, chunk=16, d_model=32,
+                                         n_layers=1, n_heads=2, d_ff=64,
+                                         vocab=256)
+    loader = make_indexed_ngram_loader(url, ngram, batch_size=4,
+                                       num_epochs=1, seed=0,
+                                       workers_count=2)
+    steps = samples = 0
+    try:
+        for batch in loader:
+            assert np.isfinite(float(step_fn(batch)))
+            steps += 1
+            samples += int(batch[0]['tokens'].shape[0])
+            if steps == 3:
+                break
+    finally:
+        loader.close()
+    assert steps == 3 and samples == 12
 
 
 def test_feeds_lm_train_step(tmp_path):

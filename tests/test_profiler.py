@@ -1,10 +1,8 @@
 """Roofline profiler tests: interval-union attribution (overlaps union, not
 sum), calibration-cache invalidation on dataset-digest change, advisor
-monotonicity, the ``/profile`` debug route (schema + 404-when-off), the
-flight-record roofline section, the perf-trajectory regression gate, and
-bench.py's bounded/atomic summary contract."""
+monotonicity, the ``/profile`` debug route (schema + 404-when-off) and the
+flight-record roofline section."""
 
-import importlib.util
 import json
 import os
 
@@ -13,19 +11,8 @@ import pytest
 from petastorm_tpu import profiler
 from petastorm_tpu.profiler import (advise, attribute, build_profile,
                                     dataset_digest, interval_union,
-                                    predict_throughput,
-                                    replay_against_artifacts,
-                                    roofline_gauges, roofline_summary)
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_script(name, rel_path):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO_ROOT, rel_path))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+                                    predict_throughput, roofline_gauges,
+                                    roofline_summary)
 
 
 def _span(name, cat, start, dur, pid=1, tid=1):
@@ -46,7 +33,7 @@ def _http_get(port, route):
 @pytest.fixture(scope='module')
 def mnist_store(tmp_path_factory):
     """A small decode-bound (png) store for calibration/profile tests."""
-    from petastorm_tpu.benchmark.northstar import \
+    from petastorm_tpu.test_util.dataset_gen import \
         generate_mnist_images_dataset
     path = tmp_path_factory.mktemp('roofline') / 'mnist'
     url = 'file://' + str(path)
@@ -316,12 +303,6 @@ class TestAdvisorModel:
         assert profile['binding_stage'] is None
         assert advise(profile) == []
 
-    def test_model_replay_against_committed_artifacts(self):
-        checks = replay_against_artifacts(REPO_ROOT)
-        assert checks, 'committed artifacts must be found in the repo'
-        bad = [c for c in checks if not c['ok']]
-        assert not bad, bad
-
 
 class TestReaderProfileSurfaces:
     def test_profile_reports_roofline_and_gauges(self, mnist_store,
@@ -418,186 +399,3 @@ class TestReaderProfileSurfaces:
         assert out['roofline']['binding_stage'] == 'decode'
         assert out['roofline']['roofline_fraction'] == 0.5
         assert 'kind' not in out['roofline']
-
-
-class TestPerfRegressionGate:
-    @pytest.fixture()
-    def gate(self):
-        return _load_script('check_perf_regression',
-                            'ci/check_perf_regression.py')
-
-    @staticmethod
-    def _overhead_artifact(value, rows=100):
-        return {'quick': False, 'rows': rows, 'workers': 2,
-                'baseline_items_per_s': value}
-
-    def _write(self, root, name, blob):
-        with open(os.path.join(str(root), name), 'w') as f:
-            json.dump(blob, f)
-
-    def test_green_trajectory_within_noise(self, gate, tmp_path):
-        self._write(tmp_path, 'BENCH_r08.json', self._overhead_artifact(100))
-        self._write(tmp_path, 'BENCH_r09.json', self._overhead_artifact(95))
-        entries, problems = gate.load_trajectory(str(tmp_path))
-        assert not problems
-        assert not gate.check_regressions(entries)
-
-    def test_seeded_regression_fails(self, gate, tmp_path):
-        self._write(tmp_path, 'BENCH_r08.json', self._overhead_artifact(100))
-        self._write(tmp_path, 'BENCH_r09.json', self._overhead_artifact(60))
-        entries, problems = gate.load_trajectory(str(tmp_path))
-        assert not problems
-        failures = gate.check_regressions(entries)
-        assert len(failures) == 1
-        assert '40.0% drop' in failures[0]
-
-    def test_dispersion_widens_the_allowance(self, gate, tmp_path):
-        # a 25% drop fails at the default 15%, passes when the series' own
-        # artifact records a 30% spread
-        base = {'value': 100.0, 'statistic': 'median',
-                'dispersion': {'spread_pct': 30.0,
-                               'protocol': {'rows': 1, 'workers': 1}},
-                'northstar': {'platform': 'cpu'}}
-        self._write(tmp_path, 'BENCH_r08.json', base)
-        self._write(tmp_path, 'BENCH_r09.json', dict(base, value=75.0))
-        entries, _ = gate.load_trajectory(str(tmp_path))
-        assert not gate.check_regressions(entries)
-
-    def test_null_parsed_artifact_rejected(self, gate, tmp_path):
-        self._write(tmp_path, 'BENCH_r13.json',
-                    {'n': 1, 'cmd': 'x', 'rc': 0, 'parsed': None})
-        _entries, problems = gate.load_trajectory(str(tmp_path))
-        assert any('null/empty "parsed"' in p for p in problems)
-
-    def test_r05_damage_is_grandfathered_but_closed(self, gate, tmp_path):
-        self._write(tmp_path, 'BENCH_r05.json',
-                    {'n': 1, 'cmd': 'x', 'rc': 0, 'parsed': None})
-        _entries, problems = gate.load_trajectory(str(tmp_path))
-        assert not problems
-        assert gate.KNOWN_DAMAGED == frozenset({'BENCH_r05.json'})
-
-    def test_new_artifact_without_roofline_context_rejected(self, gate,
-                                                            tmp_path):
-        self._write(tmp_path, 'BENCH_r12.json', self._overhead_artifact(10))
-        _entries, problems = gate.load_trajectory(str(tmp_path))
-        assert any('roofline context' in p for p in problems)
-        # the same artifact WITH roofline context passes
-        blob = dict(self._overhead_artifact(10),
-                    roofline={'roofline_pct': 41.0})
-        self._write(tmp_path, 'BENCH_r12.json', blob)
-        _entries, problems = gate.load_trajectory(str(tmp_path))
-        assert not problems
-
-    def test_bench_summary_roofline_bench_key_joins_trajectory(self, gate):
-        # bench.py's full summary nests the roofline bench under
-        # 'roofline_bench'; the normalizer must pick it up
-        summary = {'value': 10.0, 'statistic': 'median',
-                   'northstar': {'platform': 'cpu'},
-                   'roofline_bench': {
-                       'benchmark': 'roofline_mnist_decode', 'quick': True,
-                       'workers': 2, 'rows': 100,
-                       'measured_samples_per_sec': 123.0,
-                       'roofline': {'roofline_pct': 40.0}}}
-        entries, _ = gate.normalize_artifact('bench.py',
-                                             {'parsed': summary})
-        roofline = [e for e in entries
-                    if e['benchmark'] == 'roofline_mnist_decode']
-        assert len(roofline) == 1
-        assert roofline[0]['roofline_pct'] == 40.0
-
-    def test_committed_repo_trajectory_is_green(self, gate):
-        entries, problems = gate.load_trajectory(REPO_ROOT)
-        problems.extend(gate.check_regressions(entries))
-        assert not problems, problems
-        # PR 21 deleted the records taken through a remote chip setup that
-        # no longer exists (BENCH_r02-r04, the r05 dev run, MULTICHIP_r01);
-        # 31 of the 70 trajectory entries remain
-        assert len(entries) >= 31
-
-    def test_check_bench_docs_rejects_null_parsed(self, tmp_path):
-        docs_gate = _load_script('check_bench_docs',
-                                 'ci/check_bench_docs.py')
-        self._write(tmp_path, 'BENCH_r13.json', {'parsed': None})
-        errors = docs_gate.check_artifacts_intact(str(tmp_path))
-        assert len(errors) == 1 and 'null/empty' in errors[0]
-        self._write(tmp_path, 'BENCH_r05.json', {'parsed': None})
-        errors = docs_gate.check_artifacts_intact(str(tmp_path))
-        assert len(errors) == 1, 'r05 damage is grandfathered'
-
-
-class TestBenchSummaryContract:
-    @pytest.fixture()
-    def bench(self):
-        return _load_script('bench_module', 'bench.py')
-
-    @staticmethod
-    def _full_summary():
-        line = {'steps': 200, 'samples': 6400, 'samples_per_sec': 12345.67,
-                'infeed_stall_pct': 94.19, 'overlap_pct': 5.81,
-                'overlap_pct_sync': 5.5, 'roofline_pct': 41.2,
-                'roofline': {'io_decode_ceiling_samples_per_sec': 29951.1,
-                             'decode_ceiling_samples_per_sec': 31000.0,
-                             'io_ceiling_samples_per_sec': 250000.0,
-                             'cpu_count': 1}}
-        northstar = {'platform': 'tpu'}
-        for name in ('mnist_train', 'mnist_train_cached', 'transformer_train',
-                     'transformer_train_ngram',
-                     'transformer_train_ngram_indexed', 'image_decode',
-                     'imagenet_train', 'image_decode_jpeg_hinted',
-                     'imagenet_train_jpeg_hinted', 'imagenet_train_cached',
-                     'columnar_read'):
-            northstar[name] = dict(line)
-        return {
-            'metric': 'hello_world_reader_throughput', 'value': 2319.99,
-            'statistic': 'median', 'unit': 'samples/sec',
-            'vs_baseline': 3.268,
-            'dispersion': {'runs': 5, 'min': 2000.1, 'median': 2319.99,
-                           'max': 2500.5, 'spread_pct': 21.6,
-                           'protocol': {'rows': 10000, 'workers': 3}},
-            'transport': {'anything': 'large' * 200},
-            'roofline_bench': {
-                'measured_samples_per_sec': 53065.8,
-                'roofline': {'binding_stage': 'decode',
-                             'roofline_pct': 40.71}},
-            'northstar': northstar,
-        }
-
-    def test_compact_summary_is_bounded(self, bench):
-        compact = bench.compact_summary(self._full_summary(),
-                                        out_path='/tmp/bench_out.json')
-        encoded = json.dumps(compact, sort_keys=True)
-        # the r05 postmortem bound: the whole line must fit a tail-capture
-        # window with generous margin
-        assert len(encoded) < 4096, len(encoded)
-        assert compact['value'] == 2319.99
-        assert compact['northstar']['mnist_train']['sps'] == 12345.7
-        assert compact['northstar']['mnist_train']['roof'] == 41.2
-        assert compact['roofline']['binding_stage'] == 'decode'
-        # free-text and bulky blocks never reach stdout
-        assert 'transport' not in compact
-        assert 'protocol' not in compact['dispersion']
-
-    def test_emit_writes_out_atomically_and_bounds_stdout(
-            self, bench, tmp_path, capsys, monkeypatch):
-        import sys as _sys
-        gate = _load_script('check_perf_regression',
-                            'ci/check_perf_regression.py')
-        monkeypatch.setitem(_sys.modules, 'check_perf_regression', gate)
-        appended = []
-        monkeypatch.setattr(gate, 'append_entries',
-                            lambda entries, **kw: appended.extend(entries))
-        out_path = str(tmp_path / 'bench_out.json')
-        summary = self._full_summary()
-        bench.emit(summary, out_path)
-        captured = capsys.readouterr()
-        last_line = captured.out.strip().splitlines()[-1]
-        assert len(last_line) < 4096
-        assert json.loads(last_line)['value'] == 2319.99
-        # the full summary is intact on disk and no tmp file survives
-        assert json.load(open(out_path)) == summary
-        assert [p for p in os.listdir(str(tmp_path))
-                if '.tmp.' in p] == []
-        # the run joined the local perf trajectory
-        assert any(e['benchmark'] == 'hello_world' for e in appended)
-        # stderr carries the full record for humans
-        assert 'transport' in captured.err

@@ -1,7 +1,7 @@
 """Row-group readahead tests: the prefetch unit (sync/take/cancel/errors),
 the bounded file-handle LRU, reader integration across pool types and worker
 paths (row/columnar/batch/ngram), order preservation, the stats-driven auto
-depth, and the quick benchmark smoke."""
+depth, and slow storage under readahead."""
 
 import threading
 import time
@@ -394,10 +394,41 @@ def test_recommend_io_readahead_bounds():
         {'worker_io_s': 3.1, 'worker_decode_s': 1.0}) == 4
 
 
-def test_readahead_quick_benchmark_smoke():
-    """The tier-1 gate on the tentpole: the slow-IO shim must show a real
-    speedup with prefetch hits and a positive overlap fraction."""
-    from petastorm_tpu.benchmark.readahead import run_readahead_bench
-    result = run_readahead_bench(quick=True)   # asserts internally
-    assert result['readahead']['readahead_hits'] > 0
-    assert result['speedup_items_per_s'] >= 1.15
+def test_fixed_latency_storage_keeps_rows_and_counts_reads(
+        synthetic_dataset):
+    """Slow storage (faultfs' ``fixed-latency`` scenario: every ``read()``
+    sleeps, nothing fails) under readahead yields the plain reader's rows in
+    the plain reader's order, and the wrapper counts the reads it slowed."""
+    import fsspec
+
+    from petastorm_tpu.cache import NullCache
+    from petastorm_tpu.faultfs import FaultInjector, FaultyFilesystem
+    from petastorm_tpu.reader import Reader
+    from petastorm_tpu.readers.columnar_worker import (ColumnarResultsReader,
+                                                       ColumnarWorker)
+    from petastorm_tpu.workers.thread_pool import ThreadPool
+
+    with make_columnar_reader(synthetic_dataset.url, schema_fields=['id'],
+                              reader_pool_type='thread', workers_count=1,
+                              shuffle_row_groups=False,
+                              num_epochs=1) as reader:
+        expected = [list(batch.id) for batch in reader]
+    slow = FaultyFilesystem(fsspec.filesystem('file'), FaultInjector(
+        'fixed-latency', seconds_per_read=0.002))
+    reader = Reader(lambda: slow, synthetic_dataset.path,
+                    schema_fields=['id'], worker_class=ColumnarWorker,
+                    results_reader_factory=ColumnarResultsReader,
+                    shuffle_row_groups=False, num_epochs=1,
+                    cache=NullCache(), pool=ThreadPool(1, 50),
+                    is_batched_reader=True, io_readahead=2)
+    reads_at_open = slow.read_calls
+    try:
+        got = [list(batch.id) for batch in reader]
+        diag = reader.diagnostics
+    finally:
+        reader.stop()
+        reader.join()
+    assert got == expected
+    assert slow.read_calls > reads_at_open
+    assert slow.bytes_read > 0
+    assert diag['readahead_hits'] > 0

@@ -1,10 +1,19 @@
 """CLI tools + test-util components: copy_dataset, metadata_util, generate
-metadata, ReaderMock, shuffling analysis, dummy-reader microbench."""
+metadata, ReaderMock, shuffling analysis, dummy-reader microbench, and the
+package's console-script entry points."""
+
+import importlib
+import os
+import tomllib
 
 import numpy as np
 import pytest
 
 from petastorm_tpu.reader import make_reader
+
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+with open(os.path.join(REPO_ROOT, 'pyproject.toml'), 'rb') as _f:
+    CONSOLE_SCRIPTS = tomllib.load(_f)['project']['scripts']
 
 
 class TestThroughputCli:
@@ -160,31 +169,13 @@ class TestDummyReaderBench:
         assert rate > 0
 
 
-class TestInfeedOverlap:
-    def test_report_math(self):
-        from petastorm_tpu.benchmark.infeed import InfeedReport
-        r = InfeedReport(steps=10, samples=100, total_time_s=2.0,
-                         stall_time_s=0.2, compute_time_s=1.8)
-        assert r.overlap == pytest.approx(0.9)
-        assert r.stall_fraction == pytest.approx(0.1)
-        assert r.samples_per_sec == pytest.approx(50.0)
-        assert r.as_dict()['infeed_stall_pct'] == 10.0
-
-    def test_measures_loader_pipeline(self, scalar_dataset):
-        import jax.numpy as jnp
-        from petastorm_tpu.benchmark.infeed import measure_infeed_overlap
-        from petastorm_tpu.jax_utils import JaxDataLoader
-        from petastorm_tpu.reader import make_batch_reader
-
-        with make_batch_reader(scalar_dataset.url, reader_pool_type='thread',
-                               workers_count=2, num_epochs=None) as reader:
-            loader = JaxDataLoader(reader, batch_size=10)
-
-            def step(batch):
-                return jnp.sum(jnp.asarray(batch['id']))
-
-            report = measure_infeed_overlap(iter(loader), step, num_steps=20,
-                                            warmup_steps=2)
-        assert report.steps == 20
-        assert report.samples == 200
-        assert 0.0 <= report.overlap <= 1.0
+@pytest.mark.parametrize('name', sorted(CONSOLE_SCRIPTS))
+def test_console_script_resolves(name):
+    """Every ``[project.scripts]`` entry point names an importable
+    callable."""
+    module_name, _, attr = CONSOLE_SCRIPTS[name].partition(':')
+    assert attr, '{} has no callable after the colon'.format(name)
+    obj = importlib.import_module(module_name)
+    for part in attr.split('.'):
+        obj = getattr(obj, part)
+    assert callable(obj)

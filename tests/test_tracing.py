@@ -527,14 +527,3 @@ class TestAtomicExports:
         with open(path) as f:
             assert json.load(f) == {'ok': 1}
         assert [p for p in tmp_path.iterdir() if '.tmp.' in p.name] == []
-
-
-class TestTraceOverheadQuickBench:
-    @pytest.mark.timeout(300)
-    def test_quick_benchmark_smoke(self):
-        from petastorm_tpu.benchmark.trace_overhead import \
-            run_trace_overhead_bench
-        result = run_trace_overhead_bench(quick=True)
-        assert result['export_valid']
-        assert result['spans_recorded'] > 0
-        assert result['baseline_items_per_s'] > 0

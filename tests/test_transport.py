@@ -1,7 +1,7 @@
 """Zero-copy transport tests: serializer round-trips (in-process and through
-a real 2-worker ``ProcessPool``), multipart frame semantics, the
-``zmq_copy_buffers=False`` frame-lifetime regression, and the
-``benchmark/transport.py --quick`` smoke path."""
+a real 2-worker ``ProcessPool``), multipart frame semantics, the copy
+counters on both ends of the hop, and the ``zmq_copy_buffers=False``
+frame-lifetime regression."""
 
 import gc
 
@@ -15,6 +15,7 @@ from petastorm_tpu.workers.serializers import (ArrowTableSerializer,
                                                PickleSerializer,
                                                ZeroCopySerializer, as_multipart)
 from petastorm_tpu.workers.ventilator import ConcurrentVentilator
+from petastorm_tpu.workers.worker_base import WorkerBase
 
 SERIALIZERS = [PickleSerializer, ZeroCopySerializer]
 SERIALIZER_IDS = ['pickle', 'zero_copy']
@@ -170,50 +171,65 @@ def _drain(pool):
             return results
 
 
+def make_image_payload(rows, height, width):
+    """A decoded-image column batch: ``(rows, h, w, 3)`` uint8 plus labels,
+    with deterministic content."""
+    n = rows * height * width * 3
+    image = (np.arange(n, dtype=np.uint32) % 251).astype(np.uint8)
+    return {
+        'image': image.reshape(rows, height, width, 3),
+        'label': np.arange(rows, dtype=np.int64),
+    }
+
+
+class ImageStreamWorker(WorkerBase):
+    """Publishes one decoded-image column batch per ventilated item."""
+
+    def __init__(self, worker_id, publish_func, args):
+        super().__init__(worker_id, publish_func, args)
+        self._payload = make_image_payload(args['rows'], args['height'],
+                                           args['width'])
+
+    def process(self, item_index):
+        self.publish_func(self._payload)
+
+
+# zero-copy: no copy on either side; pickle: the worker's dump and the
+# consumer's load, one full-payload copy each
+@pytest.mark.parametrize('serializer_cls,copies_per_item',
+                         [(ZeroCopySerializer, 0), (PickleSerializer, 2)],
+                         ids=['zero_copy', 'pickle'])
 @pytest.mark.parametrize('zmq_copy_buffers', [True, False],
                          ids=['copy', 'nocopy'])
-def test_zero_copy_cross_process_roundtrip(zmq_copy_buffers):
+def test_zero_copy_cross_process_roundtrip(zmq_copy_buffers, serializer_cls,
+                                           copies_per_item):
     """Large decoded-image batches through a real 2-worker pool; with
     ``copy=False`` the arrays are views over ZMQ frame buffers, so content
     equality after a forced gc is the frame-lifetime regression check (a
-    ``Frame.buffer`` memoryview outliving its frame corrupts data)."""
-    from petastorm_tpu.benchmark.transport import (ImageStreamWorker,
-                                                   make_image_payload)
+    ``Frame.buffer`` memoryview outliving its frame corrupts data). The
+    pool's ``payload_copies`` counts both ends of the hop."""
     rows, h, w = 24, 96, 96    # ~0.66 MB per payload, well out-of-band
+    items = 6
     expected = make_image_payload(rows, h, w)
-    pool = ProcessPool(2, serializer=ZeroCopySerializer(),
+    pool = ProcessPool(2, serializer=serializer_cls(),
                        zmq_copy_buffers=zmq_copy_buffers)
     vent = ConcurrentVentilator(pool.ventilate,
-                                [{'item_index': i} for i in range(6)],
+                                [{'item_index': i} for i in range(items)],
                                 iterations=1)
     pool.start(ImageStreamWorker,
                worker_args={'rows': rows, 'height': h, 'width': w},
                ventilator=vent)
     try:
         results = _drain(pool)
-        assert len(results) == 6
+        assert len(results) == items
         # drop every pool-side reference we can and force collection: only
         # the received batches themselves may keep their frames alive
         gc.collect()
         for batch in results:
             np.testing.assert_array_equal(batch['image'], expected['image'])
             np.testing.assert_array_equal(batch['label'], expected['label'])
-        # worker-side serializers made zero payload copies
-        assert pool.stats.snapshot()['payload_copies'] == 0
+        assert pool.stats.snapshot()['payload_copies'] \
+            == copies_per_item * items
     finally:
         pool.stop()
         pool.join()
-
-
-def test_transport_quick_benchmark_smoke():
-    """The ``--quick`` CI path: runs the full pickle-vs-zero-copy comparison
-    (including its internal strictly-fewer-copies and >=1.5x MB/s
-    assertions) so serializer regressions fail loudly in tier-1."""
-    from petastorm_tpu.benchmark.transport import run_transport_bench
-    result = run_transport_bench(quick=True)
-    assert result['pool_stream']['zero_copy']['payload_copies'] \
-        < result['pool_stream']['pickle']['payload_copies']
-    # the counter covers BOTH ends of the hop: worker dumps + consumer loads
-    assert result['pool_stream']['pickle']['copies_per_item'] == 2.0
-    assert result['inprocess_roundtrip']['zero_copy']['copies'] == 0
-    assert result['speedup_inprocess'] >= 1.5
