@@ -18,9 +18,14 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 _NEG_INF = -1e30
+# Schedule entries one flash pallas_call takes: its four int32 tables sit in
+# SMEM, 16 bytes an entry (256 KiB here, of a v5e core's 1 MiB). Longer
+# schedules run as several calls over disjoint rows.
+_SCHEDULE_ENTRIES = 16384
 
 
 # ---------------------------------------------------------------------------
@@ -140,8 +145,7 @@ def _normalize_seg(seg, target_ndim: int, length: int, name: str):
     tracers rely on the documented contract."""
     host_side = not isinstance(seg, jax.Array)
     if host_side:
-        import numpy as _np
-        if (_np.asarray(seg) < 0).any():
+        if (np.asarray(seg) < 0).any():
             raise ValueError('%s must be non-negative (negative ids collide '
                              'with internal padding sentinels)' % name)
     seg = jnp.asarray(seg)
@@ -268,19 +272,60 @@ def _mxu_dot(a, b, dimension_numbers):
                                preferred_element_type=jnp.float32)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_q: int,
-                  block_k: int, causal: bool, scale: float, kv_seq_len: int,
-                  num_kv_blocks: int, with_lse: bool, segmented: bool = False,
-                  window=None):
-    """One (batch·head, q-block, kv-block) grid step.
+def _flash_schedule(q_len: int, kv_len: int, block_q: int, block_k: int,
+                   causal: bool, window=None, *, kv_major: bool = False):
+    """The (q-block, kv-block) tiles a flash kernel computes, in the order
+    its grid visits them — the ONE definition of which tiles are needed.
+
+    Without ``causal`` every tile of the rectangle is needed. With it, a
+    tile is needed when some pair in it has ``q_pos >= k_pos``, and under a
+    ``window`` also ``q_pos - k_pos < window``: exactly the tiles the
+    kernels' element masks do not zero out entirely.
+
+    Returns numpy int32 ``(q_blocks, kv_blocks, first, last)``, one entry
+    per tile. Row-major (each q block's kv blocks ascending, for the forward
+    and dq kernels), or ``kv_major`` (each kv block's q blocks ascending,
+    for dk/dv). ``first``/``last`` flag the first and last entry of each
+    row, where a kernel zeroes and writes its accumulators. A row with no
+    needed tile (a window or a causal rule that leaves a q row, or kv
+    column, nothing to attend) keeps its last tile, which the masks zero
+    out, so that every output block is still written."""
+    bq, bk = min(block_q, q_len), min(block_k, kv_len)
+    qi, ki = np.meshgrid(np.arange(-(-q_len // bq)),
+                         np.arange(-(-kv_len // bk)), indexing='ij')
+    needed = np.ones(qi.shape, bool)
+    if causal:
+        needed = ki * bk <= (qi + 1) * bq - 1
+        if window is not None:
+            needed &= (ki + 1) * bk - 1 >= qi * bq - window + 1
+    if kv_major:
+        needed = needed.T
+    needed[~needed.any(axis=1), -1] = True
+    rows, cols = np.nonzero(needed)            # row-major, columns ascending
+    assert np.array_equal(np.unique(rows), np.arange(needed.shape[0]))
+    step = rows[1:] != rows[:-1]
+    first = np.concatenate([[True], step])
+    last = np.concatenate([step, [True]])
+    q_blocks, kv_blocks = (cols, rows) if kv_major else (rows, cols)
+    return tuple(x.astype(np.int32)
+                 for x in (q_blocks, kv_blocks, first, last))
+
+
+def _flash_kernel(q_tab, kv_tab, first_tab, last_tab, q_ref, k_ref, v_ref,
+                  *refs, block_q: int, block_k: int, causal: bool,
+                  scale: float, kv_seq_len: int, with_lse: bool,
+                  segmented: bool = False, window=None):
+    """One (batch·head, schedule entry) grid step: the entry names the
+    (q-block, kv-block) tile (:func:`_flash_schedule`, row-major).
 
     KV **streams through the grid**: each program sees only a (block_k, D)
     slice of k/v in VMEM — bounded VMEM at any sequence length (the previous
     revision pinned the full kv sequence per program, ~2·L·D·4B, which blew
     VMEM exactly in the long-context regime the kernel exists for). The
-    online-softmax accumulators (o, m, l) persist across the sequential
-    kv-block grid dimension in VMEM scratch; the final kv step normalizes and
-    writes the output block plus its logsumexp (saved for the backward).
+    online-softmax accumulators (o, m, l) persist across a q row's entries
+    in VMEM scratch; the row's last entry normalizes and writes the output
+    block plus its logsumexp (saved for the backward). The grid holds only
+    needed tiles, so every step computes.
     ``segmented`` adds per-token segment ids (packed sequences): pairs in
     different segments are masked out.
     """
@@ -295,55 +340,45 @@ def _flash_kernel(q_ref, k_ref, v_ref, *refs, block_q: int,
         lse_ref, acc_ref, m_ref, l_ref = refs
     else:
         lse_ref, (acc_ref, m_ref, l_ref) = None, refs
-    q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
+    entry = pl.program_id(1)
+    q_idx = q_tab[entry]
+    kv_idx = kv_tab[entry]
 
-    @pl.when(kv_idx == 0)
+    @pl.when(first_tab[entry] == 1)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    s = _mxu_dot(q_ref[...], k_ref[...], (((1,), (1,)), ((), ()))) * scale
+    k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block_k), 1)
+    mask = k_pos < kv_seq_len                          # tail-padding mask
     if causal:
-        # Skip kv blocks strictly above the causal diagonal for this q block;
-        # a sliding window additionally skips blocks entirely behind it.
-        needed = kv_idx * block_k <= (q_idx + 1) * block_q - 1
+        q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        mask = mask & (q_pos >= k_pos)
         if window is not None:
-            needed &= (kv_idx + 1) * block_k - 1 >= q_idx * block_q - window + 1
-    else:
-        needed = kv_idx >= 0
+            mask = mask & (q_pos - k_pos < window)
+    if segmented:
+        # segq (bq, 1); segkv stored sublane-replicated (8, bk)
+        mask = mask & (segq_ref[...] == segkv_ref[0:1, :])
+    mask = jnp.broadcast_to(mask, s.shape)
+    s = jnp.where(mask, s, _NEG_INF)
+    m_prev = m_ref[...][:, :1]                         # (bq, 1)
+    l_prev = l_ref[...][:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    p = jnp.where(mask, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)                     # (bq, 1)
+    l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    v = v_ref[...]
+    acc_ref[...] = acc_ref[...] * corr + _mxu_dot(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(needed)
-    def _compute():
-        s = _mxu_dot(q_ref[...], k_ref[...], (((1,), (1,)), ((), ()))) * scale
-        k_pos = kv_idx * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        mask = k_pos < kv_seq_len                      # tail-padding mask
-        if causal:
-            q_pos = q_idx * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, 1), 0)
-            mask = mask & (q_pos >= k_pos)
-            if window is not None:
-                mask = mask & (q_pos - k_pos < window)
-        if segmented:
-            # segq (bq, 1); segkv stored sublane-replicated (8, bk)
-            mask = mask & (segq_ref[...] == segkv_ref[0:1, :])
-        mask = jnp.broadcast_to(mask, s.shape)
-        s = jnp.where(mask, s, _NEG_INF)
-        m_prev = m_ref[...][:, :1]                     # (bq, 1)
-        l_prev = l_ref[...][:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)                 # (bq, 1)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[...]
-        acc_ref[...] = acc_ref[...] * corr + _mxu_dot(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())))
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    @pl.when(kv_idx == num_kv_blocks - 1)
+    @pl.when(last_tab[entry] == 1)
     def _final():
         l = l_ref[...][:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -366,6 +401,44 @@ def _out_vma(*arrays):
 
 def _sds(shape, dtype, vma):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
+
+
+def _scheduled_call(kernel, dims: '_FlashDims', pieces, in_specs, inputs,
+                    outs, scratch_shapes, *, kv_major: bool, vma,
+                    interpret: bool):
+    """``kernel`` over the compacted grid ``(flat, entries)`` of each piece
+    of a schedule (:meth:`_FlashDims.schedule`), the piece's tables
+    prefetched into SMEM ahead of ``inputs``. ``outs`` gives each output's
+    ``(dtype, width)``; its row blocks are the schedule's rows (q blocks,
+    or kv blocks when ``kv_major``), and the pieces' rows are concatenated.
+    """
+    from jax.experimental import pallas as pl
+    import jax.experimental.pallas.tpu as pltpu
+
+    block = dims.bk if kv_major else dims.bq
+    results = []
+    for row0, row1, tables in pieces:
+        def out_rows(b, e, qt, kt, *_, row0=row0):
+            return (b, (kt if kv_major else qt)[e] - row0, 0)
+
+        results.append(pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=len(tables),
+                grid=(dims.flat, len(tables[0])),
+                in_specs=in_specs,
+                out_specs=[pl.BlockSpec((None, block, width), out_rows)
+                           for _, width in outs],
+                scratch_shapes=scratch_shapes),
+            out_shape=[_sds((dims.flat, (row1 - row0) * block, width),
+                            dtype, vma) for dtype, width in outs],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=('parallel', 'arbitrary')),
+            interpret=interpret,
+        )(*tables, *inputs))
+    if len(results) == 1:
+        return results[0]
+    return [jnp.concatenate(parts, axis=1) for parts in zip(*results)]
 
 
 class _FlashDims:
@@ -415,9 +488,27 @@ class _FlashDims:
         self.pq_len, self.pk_len = q_len + self.pad_q, kv_len + self.pad_k
         self.flat = int(math.prod(batch)) if batch else 1
         self.kv_flat = int(math.prod(kv_batch)) if kv_batch else 1
-        self.num_q_blocks = self.pq_len // self.bq
-        self.num_kv_blocks = self.pk_len // self.bk
         self.scale = 1.0 / math.sqrt(head_dim)
+
+    def schedule(self, causal: bool, window, kv_major: bool = False):
+        """:func:`_flash_schedule` at these block sizes, cut at row
+        boundaries into pieces of at most ``_SCHEDULE_ENTRIES`` entries:
+        ``[(first_row, end_row, tables)]``, the tables as the int32 arrays a
+        piece's grid prefetches into SMEM (a row longer than that is a piece
+        of its own). One piece unless the schedule is long (causal at
+        blocks 256/512: L over 64k)."""
+        tables = _flash_schedule(self.q_len, self.kv_len, self.bq, self.bk,
+                                 causal, window, kv_major=kv_major)
+        row_start = list(np.flatnonzero(tables[2])) + [len(tables[2])]
+        cuts = [0]
+        for row in range(2, len(row_start)):
+            if (row_start[row] - row_start[cuts[-1]] > _SCHEDULE_ENTRIES
+                    and row - 1 > cuts[-1]):
+                cuts.append(row - 1)
+        cuts.append(len(row_start) - 1)
+        return [(lo, hi, [jnp.asarray(t[row_start[lo]:row_start[hi]])
+                          for t in tables])
+                for lo, hi in zip(cuts[:-1], cuts[1:])]
 
     def kv_program_index(self):
         """flat q-program index → flat kv row index (identity unless GQA)."""
@@ -506,9 +597,7 @@ def _pallas_flash(q, k, v, causal: bool, block_q: int, block_k: int,
 
     dims = _FlashDims(q.shape, k.shape, block_q, block_k)
     batch, q_len, head_dim = dims.batch, dims.q_len, dims.head_dim
-    kv_len, bq, bk, flat = dims.kv_len, dims.bq, dims.bk, dims.flat
-    pq_len, num_kv_blocks = dims.pq_len, dims.num_kv_blocks
-    scale = dims.scale
+    kv_len, bq, bk, scale = dims.kv_len, dims.bq, dims.bk, dims.scale
     kvmap = dims.kv_program_index()
     qf = dims.pad_q_like(q)
     kf = dims.pad_kv_like(k)
@@ -516,45 +605,40 @@ def _pallas_flash(q, k, v, causal: bool, block_q: int, block_k: int,
     seg_q, kv_seg = _resolve_segs(segment_ids, kv_segment_ids, q.ndim,
                                   k.ndim, q_len, kv_len)
     segmented = seg_q is not None
-    in_specs = [
-        pl.BlockSpec((None, bq, head_dim), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((None, bk, head_dim), lambda b, i, j: (kvmap(b), j, 0)),
-        pl.BlockSpec((None, bk, head_dim), lambda b, i, j: (kvmap(b), j, 0)),
-    ]
+
+    # index maps: grid (b, entry), then the prefetched tables
+    def q_rows(b, e, qt, kt, *_):
+        return (b, qt[e], 0)
+
+    def kv_rows(b, e, qt, kt, *_):
+        return (kvmap(b), kt[e], 0)
+
+    in_specs = [pl.BlockSpec((None, bq, head_dim), q_rows),
+                pl.BlockSpec((None, bk, head_dim), kv_rows),
+                pl.BlockSpec((None, bk, head_dim), kv_rows)]
     inputs = [qf, kf, vf]
     if segmented:
         dims.check_segment_blocks(interpret)
         inputs += [dims.pad_seg_q(seg_q), dims.pad_seg_kv(kv_seg)]
         in_specs += [
-            pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((None, 8, bk), lambda b, i, j: (kvmap(b), 0, j)),
+            pl.BlockSpec((None, bq, 1), q_rows),
+            pl.BlockSpec((None, 8, bk),
+                         lambda b, e, qt, kt, *_: (kvmap(b), 0, kt[e])),
         ]
 
     kernel = functools.partial(
         _flash_kernel, block_q=bq, block_k=bk, causal=causal, scale=scale,
-        kv_seq_len=kv_len, num_kv_blocks=num_kv_blocks, with_lse=with_lse,
-        segmented=segmented, window=window)
-    vma = _out_vma(q, k, v)
-    out_specs = [pl.BlockSpec((None, bq, head_dim), lambda b, i, j: (b, i, 0))]
-    out_shape = [_sds((flat, pq_len, head_dim), q.dtype, vma)]
+        kv_seq_len=kv_len, with_lse=with_lse, segmented=segmented,
+        window=window)
+    outs = [(q.dtype, head_dim)]
     if with_lse:
-        out_specs.append(pl.BlockSpec((None, bq, 128), lambda b, i, j: (b, i, 0)))
-        out_shape.append(_sds((flat, pq_len, 128), jnp.float32, vma))
-    result = pl.pallas_call(
-        kernel,
-        grid=(flat, pq_len // bq, num_kv_blocks),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((bq, head_dim), jnp.float32),   # o accumulator
-            pltpu.VMEM((bq, 128), jnp.float32),        # running max (lanes equal)
-            pltpu.VMEM((bq, 128), jnp.float32),        # running sum (lanes equal)
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
-        interpret=interpret,
-    )(*inputs)
+        outs.append((jnp.float32, 128))
+    result = _scheduled_call(
+        kernel, dims, dims.schedule(causal, window), in_specs, inputs, outs,
+        [pltpu.VMEM((bq, head_dim), jnp.float32),      # o accumulator
+         pltpu.VMEM((bq, 128), jnp.float32),           # running max
+         pltpu.VMEM((bq, 128), jnp.float32)],          # running sum
+        kv_major=False, vma=_out_vma(q, k, v), interpret=interpret)
     o = dims.unpad_q_like(result[0])
     if not with_lse:
         return o, None
@@ -653,14 +737,15 @@ def _bwd_recompute_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *,
     return q, k, do, p, ds
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         *refs, block_q: int, block_k: int,
-                         causal: bool, scale: float, kv_seq_len: int,
-                         num_kv_blocks: int, segmented: bool = False,
-                         window=None):
-    """dq pass: one (batch·head, q-block, kv-block) grid step; kv streams
-    through the grid (like the forward), dq accumulates in VMEM scratch across
-    the sequential kv dimension and is written on the final kv step.
+def _flash_bwd_dq_kernel(q_tab, kv_tab, first_tab, last_tab, q_ref, k_ref,
+                         v_ref, do_ref, lse_ref, delta_ref, *refs,
+                         block_q: int, block_k: int, causal: bool,
+                         scale: float, kv_seq_len: int,
+                         segmented: bool = False, window=None):
+    """dq pass: one (batch·head, schedule entry) grid step over the
+    row-major :func:`_flash_schedule`; kv streams through the grid (like the
+    forward), dq accumulates in VMEM scratch across a q row's entries and is
+    written on the row's last one.
 
     p is recomputed from (q, k, lse); ds = p·(do·vᵀ − Δ)·scale with
     Δ = rowsum(do·o) precomputed outside the kernel."""
@@ -671,44 +756,34 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         segq_ref = segkv_ref = None
         dq_ref, dq_acc = refs
-    q_idx = pl.program_id(1)
-    kv_idx = pl.program_id(2)
+    entry = pl.program_id(1)
 
-    @pl.when(kv_idx == 0)
+    @pl.when(first_tab[entry] == 1)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    if causal:
-        needed = kv_idx * block_k <= (q_idx + 1) * block_q - 1
-        if window is not None:
-            needed &= (kv_idx + 1) * block_k - 1 >= q_idx * block_q - window + 1
-    else:
-        needed = kv_idx >= 0
+    _, k, _, _, ds = _bwd_recompute_p_ds(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        q_idx=q_tab[entry], kv_idx=kv_tab[entry], block_q=block_q,
+        block_k=block_k, causal=causal, scale=scale, kv_seq_len=kv_seq_len,
+        segq_ref=segq_ref, segkv_ref=segkv_ref, window=window)
+    dq_acc[...] += _mxu_dot(ds.astype(k.dtype), k, (((1,), (0,)), ((), ())))
 
-    @pl.when(needed)
-    def _compute():
-        _, k, _, _, ds = _bwd_recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_idx=q_idx,
-            kv_idx=kv_idx, block_q=block_q, block_k=block_k, causal=causal,
-            scale=scale, kv_seq_len=kv_seq_len, segq_ref=segq_ref,
-            segkv_ref=segkv_ref, window=window)
-        dq_acc[...] += _mxu_dot(ds.astype(k.dtype), k,
-                                (((1,), (0,)), ((), ())))
-
-    @pl.when(kv_idx == num_kv_blocks - 1)
+    @pl.when(last_tab[entry] == 1)
     def _final():
         dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           *refs, block_q: int,
-                           block_k: int, causal: bool, scale: float,
-                           kv_seq_len: int, num_q_blocks: int,
+def _flash_bwd_dkdv_kernel(q_tab, kv_tab, first_tab, last_tab, q_ref, k_ref,
+                           v_ref, do_ref, lse_ref, delta_ref, *refs,
+                           block_q: int, block_k: int, causal: bool,
+                           scale: float, kv_seq_len: int,
                            segmented: bool = False, window=None):
-    """dk/dv pass: one (batch·head, kv-block, q-block) grid step; q (and do,
-    lse, Δ) stream through the grid, dk/dv accumulate in VMEM scratch across
-    the sequential q dimension. Padded q rows carry do == 0, so they
-    contribute nothing and need no extra mask."""
+    """dk/dv pass: one (batch·head, schedule entry) grid step over the
+    kv-major :func:`_flash_schedule`; q (and do, lse, Δ) stream through the
+    grid, dk/dv accumulate in VMEM scratch across a kv column's entries.
+    Padded q rows carry do == 0, so they contribute nothing and need no
+    extra mask."""
     from jax.experimental import pallas as pl
 
     if segmented:
@@ -716,34 +791,24 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         segq_ref = segkv_ref = None
         dk_ref, dv_ref, dk_acc, dv_acc = refs
-    kv_idx = pl.program_id(1)
-    q_idx = pl.program_id(2)
+    entry = pl.program_id(1)
 
-    @pl.when(q_idx == 0)
+    @pl.when(first_tab[entry] == 1)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        needed = (q_idx + 1) * block_q - 1 >= kv_idx * block_k
-        if window is not None:
-            needed &= (kv_idx + 1) * block_k - 1 >= q_idx * block_q - window + 1
-    else:
-        needed = q_idx >= 0
+    q, _, do, p, ds = _bwd_recompute_p_ds(
+        q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+        q_idx=q_tab[entry], kv_idx=kv_tab[entry], block_q=block_q,
+        block_k=block_k, causal=causal, scale=scale, kv_seq_len=kv_seq_len,
+        segq_ref=segq_ref, segkv_ref=segkv_ref, window=window)
+    dv_acc[...] += _mxu_dot(p.astype(do.dtype), do,
+                            (((0,), (0,)), ((), ())))       # (bk, D)
+    dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q,
+                            (((0,), (0,)), ((), ())))       # (bk, D)
 
-    @pl.when(needed)
-    def _compute():
-        q, _, do, p, ds = _bwd_recompute_p_ds(
-            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, q_idx=q_idx,
-            kv_idx=kv_idx, block_q=block_q, block_k=block_k, causal=causal,
-            scale=scale, kv_seq_len=kv_seq_len, segq_ref=segq_ref,
-            segkv_ref=segkv_ref, window=window)
-        dv_acc[...] += _mxu_dot(p.astype(do.dtype), do,
-                                (((0,), (0,)), ((), ())))   # (bk, D)
-        dk_acc[...] += _mxu_dot(ds.astype(q.dtype), q,
-                                (((0,), (0,)), ((), ())))   # (bk, D)
-
-    @pl.when(q_idx == num_q_blocks - 1)
+    @pl.when(last_tab[entry] == 1)
     def _final():
         dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -800,74 +865,52 @@ def _flash_backward_from_prepared(dims: '_FlashDims', prep, k, v, *,
 
     qf, dof, lsef, deltaf = prep
     kv_len, head_dim, bq, bk = dims.kv_len, dims.head_dim, dims.bq, dims.bk
-    flat, pq_len, pk_len = dims.flat, dims.pq_len, dims.pk_len
-    num_q_blocks, num_kv_blocks = dims.num_q_blocks, dims.num_kv_blocks
     scale = dims.scale
     kvmap = dims.kv_program_index()
     kf = dims.pad_kv_like(k)
     vf = dims.pad_kv_like(v)
     vma = _out_vma(qf, k, v, dof)
     segmented = segs is not None
+    kernel_args = dict(block_q=bq, block_k=bk, causal=causal, scale=scale,
+                       kv_seq_len=kv_len, segmented=segmented, window=window)
 
-    qspec = pl.BlockSpec((None, bq, head_dim), lambda b, i, j: (b, i, 0))
-    kvspec_j = pl.BlockSpec((None, bk, head_dim),
-                            lambda b, i, j: (kvmap(b), j, 0))
-    rowspec_i = pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, i, 0))
-    dq_inputs = [qf, kf, vf, dof, lsef, deltaf]
-    dq_specs = [qspec, kvspec_j, kvspec_j, qspec, rowspec_i, rowspec_i]
-    if segmented:
-        dq_inputs += list(segs)
-        dq_specs += [rowspec_i,
-                     pl.BlockSpec((None, 8, bk),
-                                  lambda b, i, j: (kvmap(b), 0, j))]
-    dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
-                          causal=causal, scale=scale, kv_seq_len=kv_len,
-                          num_kv_blocks=num_kv_blocks, segmented=segmented,
-                          window=window),
-        grid=(flat, num_q_blocks, num_kv_blocks),
-        in_specs=dq_specs,
-        out_specs=qspec,
-        out_shape=_sds((flat, pq_len, head_dim), qf.dtype, vma),
-        scratch_shapes=[pltpu.VMEM((bq, head_dim), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
-        interpret=interpret,
-    )(*dq_inputs)
+    # index maps: grid (b, entry), then the prefetched tables
+    def q_rows(b, e, qt, kt, *_):
+        return (b, qt[e], 0)
 
-    qspec_j = pl.BlockSpec((None, bq, head_dim), lambda b, i, j: (b, j, 0))
-    kvspec_i = pl.BlockSpec((None, bk, head_dim),
-                            lambda b, i, j: (kvmap(b), i, 0))
-    outspec_i = pl.BlockSpec((None, bk, head_dim), lambda b, i, j: (b, i, 0))
-    rowspec_j = pl.BlockSpec((None, bq, 1), lambda b, i, j: (b, j, 0))
-    dkdv_inputs = [qf, kf, vf, dof, lsef, deltaf]
-    dkdv_specs = [qspec_j, kvspec_i, kvspec_i, qspec_j, rowspec_j, rowspec_j]
+    def kv_rows(b, e, qt, kt, *_):
+        return (kvmap(b), kt[e], 0)
+
+    def kv_seg_lanes(b, e, qt, kt, *_):
+        return (kvmap(b), 0, kt[e])
+
+    qspec = pl.BlockSpec((None, bq, head_dim), q_rows)
+    kvspec = pl.BlockSpec((None, bk, head_dim), kv_rows)
+    rowspec = pl.BlockSpec((None, bq, 1), q_rows)
+    inputs = [qf, kf, vf, dof, lsef, deltaf]
+    specs = [qspec, kvspec, kvspec, qspec, rowspec, rowspec]
     if segmented:
-        dkdv_inputs += list(segs)
-        dkdv_specs += [rowspec_j,
-                       pl.BlockSpec((None, 8, bk),
-                                    lambda b, i, j: (kvmap(b), 0, i))]
+        inputs += list(segs)
+        specs += [rowspec, pl.BlockSpec((None, 8, bk), kv_seg_lanes)]
+
+    def call(kernel, kv_major, out_dtypes, scratch_shapes):
+        return _scheduled_call(
+            functools.partial(kernel, **kernel_args), dims,
+            dims.schedule(causal, window, kv_major=kv_major), specs, inputs,
+            [(dtype, head_dim) for dtype in out_dtypes], scratch_shapes,
+            kv_major=kv_major, vma=vma, interpret=interpret)
+
+    dq, = call(_flash_bwd_dq_kernel, False, [qf.dtype],
+               [pltpu.VMEM((bq, head_dim), jnp.float32)])
+
     # GQA emits per-Q-head float32 partials (exact cross-head sum before the
     # storage cast); plain MHA writes k/v dtype directly — no extra HBM
     # traffic or cast pass on the common path
     part_dtypes = ((jnp.float32, jnp.float32) if dims.group > 1
                    else (k.dtype, v.dtype))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_dkdv_kernel, block_q=bq, block_k=bk,
-                          causal=causal, scale=scale, kv_seq_len=kv_len,
-                          num_q_blocks=num_q_blocks, segmented=segmented,
-                          window=window),
-        grid=(flat, num_kv_blocks, num_q_blocks),
-        in_specs=dkdv_specs,
-        out_specs=[outspec_i, outspec_i],
-        out_shape=[_sds((flat, pk_len, head_dim), part_dtypes[0], vma),
-                   _sds((flat, pk_len, head_dim), part_dtypes[1], vma)],
-        scratch_shapes=[pltpu.VMEM((bk, head_dim), jnp.float32),
-                        pltpu.VMEM((bk, head_dim), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('parallel', 'parallel', 'arbitrary')),
-        interpret=interpret,
-    )(*dkdv_inputs)
+    dk, dv = call(_flash_bwd_dkdv_kernel, True, part_dtypes,
+                  [pltpu.VMEM((bk, head_dim), jnp.float32),
+                   pltpu.VMEM((bk, head_dim), jnp.float32)])
 
     if dims.group > 1:
         dk = dims.sum_head_groups(dk).astype(k.dtype)

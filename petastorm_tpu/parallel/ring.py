@@ -279,8 +279,12 @@ def make_ring_attention(mesh, seq_axis: str = 'seq', causal: bool = True,
     spec = P(batch_axis, None, seq_axis, None)
     impl = resolve_ring_impl(impl, mesh)
 
+    # The Pallas interpreter evaluates a kernel body op by op, with its
+    # scratch and output blocks typed as invariant over the mesh: shard_map's
+    # varying-axes check would refuse their first product with an input.
     @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(spec, spec, spec), out_specs=spec)
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=impl != 'interpret')
     def fn(q, k, v):
         return ring_attention(q, k, v, seq_axis, causal=causal, impl=impl)
 

@@ -13,7 +13,8 @@ import pytest
 
 from conftest import ref_attention as _exact
 
-from petastorm_tpu.ops.attention import blockwise_attention, flash_attention
+from petastorm_tpu.ops.attention import (
+    _flash_schedule, blockwise_attention, flash_attention)
 
 # kernels / model training are minutes-scale: the fast lane skips the classes
 # that run them, and keeps the jaxpr checks, which only trace
@@ -99,6 +100,152 @@ def test_kernel_mxu_operands_follow_input_dtype(dtype, segmented, grad):
         assert [x.aval.dtype for x in eqn.invars] == [dtype, dtype], eqn
         assert eqn.params['preferred_element_type'] == jnp.float32, eqn
         assert eqn.params['precision'] == (precision, precision), eqn
+
+
+def _tile_has_live_pair(qi, ki, bq, bk, causal, window):
+    """Whether any (q, k) position pair of tile (qi, ki) passes the
+    kernels' causal and window element masks."""
+    if not causal:
+        return True
+    q_pos = qi * bq + np.arange(bq)[:, None]
+    k_pos = ki * bk + np.arange(bk)[None, :]
+    live = q_pos >= k_pos
+    if window is not None:
+        live &= q_pos - k_pos < window
+    return bool(live.any())
+
+
+@pytest.mark.parametrize('block_q,block_k', [(256, 512), (128, 128)])
+@pytest.mark.parametrize('q_len,kv_len', [
+    (2048, 2048), (1000, 1000), (300, 300),     # block multiples and not
+    (2048, 1000), (1000, 300), (300, 2048),     # q_len != kv_len
+])
+@pytest.mark.parametrize('causal,window', [
+    (False, None), (True, None), (True, 256), (True, 700)])
+def test_flash_schedule_enumerates_the_needed_tiles(q_len, kv_len, block_q,
+                                                    block_k, causal, window):
+    """The grid's tables hold each tile the kernels' masks leave live, once,
+    row by row with columns ascending (q rows for the forward and dq, kv
+    columns for dk/dv); every row has one first and one last flag, and a
+    row with no live tile keeps exactly its last tile, all masked."""
+    bq, bk = min(block_q, q_len), min(block_k, kv_len)
+    nq, nk = -(-q_len // bq), -(-kv_len // bk)
+    live = np.array([[_tile_has_live_pair(i, j, bq, bk, causal, window)
+                      for j in range(nk)] for i in range(nq)])
+    for kv_major in (False, True):
+        q_blk, kv_blk, first, last = _flash_schedule(
+            q_len, kv_len, block_q, block_k, causal, window,
+            kv_major=kv_major)
+        for t in (q_blk, kv_blk, first, last):
+            assert t.dtype == np.int32 and t.shape == q_blk.shape
+        rows, cols = (kv_blk, q_blk) if kv_major else (q_blk, kv_blk)
+        grid = live.T if kv_major else live
+        want = []
+        for r in range(grid.shape[0]):
+            row = [(r, c) for c in range(grid.shape[1]) if grid[r, c]]
+            want += row or [(r, grid.shape[1] - 1)]
+        assert list(zip(rows.tolist(), cols.tolist())) == want
+        for r in range(grid.shape[0]):
+            at = np.flatnonzero(rows == r)
+            assert at.size >= 1, 'row %d is empty' % r
+            assert first[at].tolist() == [1] + [0] * (at.size - 1)
+            assert last[at].tolist() == [0] * (at.size - 1) + [1]
+
+
+def test_flash_schedule_at_the_lm_shapes():
+    """L 2048, blocks 256/512, causal: 20 of the rectangle's 32 tiles, in
+    each order (1, 1, 2, 2, 3, 3, 4, 4 kv blocks for q blocks 0-7)."""
+    q_blk, kv_blk, first, _ = _flash_schedule(2048, 2048, 256, 512, True)
+    assert len(q_blk) == 20
+    assert np.bincount(q_blk).tolist() == [1, 1, 2, 2, 3, 3, 4, 4]
+    q_blk, kv_blk, first, _ = _flash_schedule(2048, 2048, 256, 512, True,
+                                              kv_major=True)
+    assert len(q_blk) == 20 and int(first.sum()) == 4
+    assert np.bincount(kv_blk).tolist() == [8, 6, 4, 2]
+    assert len(_flash_schedule(2048, 2048, 256, 512, False)[0]) == 32
+
+
+@pytest.mark.parametrize('cap', [1, 5, 40])
+@pytest.mark.parametrize('kv_major', [False, True])
+def test_flash_schedule_pieces_cut_at_row_boundaries(monkeypatch, cap,
+                                                     kv_major):
+    """A schedule longer than one call's tables is cut into pieces at row
+    boundaries, each within the cap (a row longer than the cap stays
+    whole, alone in its piece), that together hold the schedule in order."""
+    from petastorm_tpu.ops import attention
+    from petastorm_tpu.ops.attention import _FlashDims
+
+    monkeypatch.setattr(attention, '_SCHEDULE_ENTRIES', cap)
+    dims = _FlashDims((1, 2, 1000, 32), (1, 2, 1000, 32), 64, 128)
+    whole = _flash_schedule(1000, 1000, 64, 128, True, 300,
+                            kv_major=kv_major)
+    pieces = dims.schedule(True, 300, kv_major=kv_major)
+    rows = whole[1] if kv_major else whole[0]
+    assert pieces[0][0] == 0 and pieces[-1][1] == rows.max() + 1
+    for (_, hi, _), (lo, _, _) in zip(pieces, pieces[1:]):
+        assert hi == lo
+    for lo, hi, tables in pieces:
+        piece_rows = np.asarray(tables[1] if kv_major else tables[0])
+        assert piece_rows.min() == lo and piece_rows.max() == hi - 1
+        assert len(piece_rows) <= cap or hi - lo == 1
+        assert int(np.asarray(tables[2])[0]) == 1
+        assert int(np.asarray(tables[3])[-1]) == 1
+    for i, t in enumerate(whole):
+        assert np.concatenate(
+            [np.asarray(p[2][i]) for p in pieces]).tolist() == t.tolist()
+    if cap == 40:
+        assert len(pieces) < len(np.unique(rows))
+
+
+@pytest.mark.parametrize('case', [
+    'causal', 'window', 'segments', 'gqa',
+    'window_past_kv',           # q rows past kv + window: no live tile
+    'pieces',                   # each kernel's schedule cut into 2-3 calls
+])
+def test_scheduled_kernels_match_oracles(cpu, monkeypatch, case):
+    """The kernels on their scheduled grids, at a few seconds' size in
+    interpret mode (B·H 2, L 384, blocks 128): o against
+    ``blockwise_attention``, the fused backward against its autodiff and
+    against the ``bwd='jnp'`` oracle."""
+    rng = np.random.default_rng(3)
+    h, hkv, lq, lk = {'gqa': (2, 1, 384, 384),
+                      'window_past_kv': (2, 2, 384, 128)}.get(
+                          case, (2, 2, 384, 384))
+    q, do = (jnp.asarray(rng.standard_normal((1, h, lq, 32)), jnp.float32)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.standard_normal((1, hkv, lk, 32)), jnp.float32)
+            for _ in range(2))
+    window = {'window': 150, 'window_past_kv': 64}.get(case)
+    if case == 'pieces':
+        from petastorm_tpu.ops import attention
+        monkeypatch.setattr(attention, '_SCHEDULE_ENTRIES', 3)
+    seg = (jnp.asarray(np.repeat([0, 1, 2], [100, 180, 104])[None])
+           if case == 'segments' else None)
+
+    def attend(backend, bwd=None):
+        return lambda q, k, v: flash_attention(
+            q, k, v, causal=True, block_q=128, block_k=128, backend=backend,
+            bwd=bwd, segment_ids=seg, window=window)
+
+    def reference(q, k, v):
+        g = h // hkv
+        return blockwise_attention(
+            q, jnp.repeat(k, g, axis=-3), jnp.repeat(v, g, axis=-3),
+            causal=True, block_k=128, segment_ids=seg, window=window)
+
+    o, vjp = jax.vjp(attend('interpret'), q, k, v)
+    grads = vjp(do)
+    np.testing.assert_allclose(np.asarray(o),
+                               np.asarray(reference(q, k, v)),
+                               atol=1e-4, rtol=1e-4)
+    _, ref_vjp = jax.vjp(reference, q, k, v)
+    for a, b in zip(grads, ref_vjp(do)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=2e-3, rtol=1e-3)
+    _, jnp_vjp = jax.vjp(attend('interpret', 'jnp'), q, k, v)
+    for a, b in zip(grads, jnp_vjp(do)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-5)
 
 
 @slow

@@ -99,6 +99,48 @@ def test_flash_segmented_backward_at_lm_widths(topo, precision):
     assert hlo.count('tpu_custom_call') >= 3
 
 
+@pytest.mark.parametrize('window,segmented', [(512, False), (None, True),
+                                              (700, True)])
+def test_flash_scheduled_backward(topo, window, segmented):
+    """Forward and fused backward on their scheduled grids (the tiles'
+    tables prefetched into SMEM) at the LM's per-chip widths, with a
+    sliding window, with segment ids, and with both."""
+    from petastorm_tpu.ops.attention import flash_attention
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    qkv = [jax.ShapeDtypeStruct((4, 12, 2048, 64), jnp.bfloat16,
+                                sharding=chip) for _ in range(3)]
+    seg = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=chip)
+
+    def loss(q, k, v, seg):
+        o = flash_attention(q, k, v, causal=True, backend='pallas',
+                            bwd='pallas', window=window,
+                            segment_ids=seg if segmented else None)
+        return jnp.sum(o.astype(jnp.float32))
+
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), *qkv, seg)
+    assert hlo.count('tpu_custom_call') >= 3
+
+
+def test_flash_long_sequence_schedule_in_pieces(topo):
+    """At L 128k the causal schedule has 65,792 tiles a kernel, whose
+    tables would outgrow the core's 1 MiB of SMEM in one call: each kernel
+    runs as several calls over disjoint rows."""
+    from petastorm_tpu.ops.attention import flash_attention
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    qkv = [jax.ShapeDtypeStruct((1, 1, 131072, 64), jnp.bfloat16,
+                                sharding=chip) for _ in range(3)]
+
+    def loss(q, k, v):
+        o = flash_attention(q, k, v, causal=True, backend='pallas',
+                            bwd='pallas')
+        return jnp.sum(o.astype(jnp.float32))
+
+    hlo = _hlo(jax.grad(loss, argnums=(0, 1, 2)), *qkv)
+    assert hlo.count('tpu_custom_call') >= 3 * 5
+
+
 def test_flash_sharded_over_data_mesh(topo):
     """The LM's flash call on a data-parallel mesh: GSPMD refuses a bare
     Mosaic kernel, so it must sit inside shard_map over 'data'."""
